@@ -23,12 +23,17 @@ import json
 from dataclasses import dataclass
 from pathlib import Path
 
+try:  # run as a script: benchmarks/ is on sys.path
+    from peaks import peaks_for
+except ImportError:  # imported as benchmarks.roofline (report.py, run.py)
+    from benchmarks.peaks import peaks_for
 from repro.configs import SHAPES, applicable, get_config, list_archs
 from repro.configs.base import ArchConfig
 
-PEAK_FLOPS = 197e12  # bf16 / chip (TPU v5e)
-HBM_BW = 819e9  # B/s / chip
-ICI_BW = 50e9  # B/s / link
+_CHIP = peaks_for("TPU v5 lite")  # the dry-run's target: a v5e pod
+PEAK_FLOPS = _CHIP["bf16_flops"]  # per chip
+HBM_BW = _CHIP["hbm_gbps"] * 1e9  # B/s per chip
+ICI_BW = _CHIP["ici_link_gbps"] * 1e9  # B/s per link
 BP = 2  # param bytes (bf16)
 BA = 2  # activation bytes (bf16)
 

@@ -5,10 +5,10 @@ over the fused bank-inference kernel (kernels.ops.predict_bank) and over the
 end-to-end BankServer microbatching path, measures seconds/batch, queries/s
 and model-scores/s (Q * B margins evaluated per batch), derives achieved
 GB/s from the engine's modeled HBM byte traffic, and compares against the
-same bandwidth roofline as the training harness (default TPU v5e 819 GB/s
-per chip — override with ``--hbm-peak-gbps`` or ``REPRO_HBM_PEAK_GBPS`` for
-TPU-measured runs; on the CPU interpret backend the roofline fraction is a
-trend number only). ``bank_resident="hbm"`` rows serve the bank out of
+same bandwidth roofline as the training harness (the device's published HBM
+peak from ``peaks.py``, keyed by ``device_kind`` — a device without one is
+an error unless ``--hbm-peak-gbps`` or ``REPRO_HBM_PEAK_GBPS`` names a
+peak; on the CPU interpret backend no number it prints is a device metric). ``bank_resident="hbm"`` rows serve the bank out of
 ANY/HBM space through the kernel's 2-slot async-copy ring instead of the
 BlockSpec pipeline — same modeled bytes (the bank is re-read once per
 resident query tile either way), so the wall-time ratio against the
@@ -58,6 +58,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+try:  # run as a script: benchmarks/ is on sys.path
+    from peaks import peaks_for
+except ImportError:  # imported as benchmarks.<harness> (run.py)
+    from benchmarks.peaks import peaks_for
 from repro.kernels import predict_bank, predict_kernel_bank
 from repro.kernels.ops import (
     bank_tiling,
@@ -65,20 +69,23 @@ from repro.kernels.ops import (
     ovr_group_tiling,
     predict_vmem_bytes,
 )
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve import BankServer
 
 SCHEMA = "streamsvm-bench-serving/v5"
-DEFAULT_HBM_PEAK_GBPS = 819.0  # TPU v5e, per chip — same as BENCH_engine
 _DTYPE_BYTES = {"f32": 4, "bf16": 2}
 
 
 def hbm_peak_gbps(override=None) -> float:
-    """Roofline peak: --hbm-peak-gbps flag > REPRO_HBM_PEAK_GBPS env >
-    the TPU v5e default — so TPU-measured runs never need a source edit."""
+    """Roofline peak: --hbm-peak-gbps flag > REPRO_HBM_PEAK_GBPS env > the
+    published peak of the device JAX runs on (``peaks.py``, keyed by
+    ``device_kind``). A device without a published peak is an error."""
     if override is not None:
         return float(override)
     env = os.environ.get("REPRO_HBM_PEAK_GBPS")
-    return float(env) if env else DEFAULT_HBM_PEAK_GBPS
+    if env:
+        return float(env)
+    return peaks_for(jax.devices()[0].device_kind)["hbm_gbps"]
 
 
 # Keys every result row must carry — CI validates the emitted JSON against
@@ -755,7 +762,7 @@ def main(argv=None):
     ap.add_argument(
         "--hbm-peak-gbps", type=float, default=None, metavar="GBPS",
         help="HBM roofline peak in GB/s (default: REPRO_HBM_PEAK_GBPS env "
-        f"var, else {DEFAULT_HBM_PEAK_GBPS} — TPU v5e per chip)",
+        "var, else the device's published peak from benchmarks/peaks.py)",
     )
     ap.add_argument(
         "--filter", default=None, metavar="SUBSTR",
@@ -768,6 +775,7 @@ def main(argv=None):
     )
     args = ap.parse_args(argv)
     interpret = None if args.interpret is None else args.interpret == "true"
+    use_compile_cache(Path(__file__).resolve().parent.parent)
 
     report = run(args.smoke, args.reps, interpret, name_filter=args.filter,
                  peak_gbps=args.hbm_peak_gbps)

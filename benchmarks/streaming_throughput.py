@@ -3,10 +3,11 @@
 Sweeps (B, D, N, block_n, b_tile, stream_dtype, variant, n_shards,
 bank_resident) over the tiled multi-ball engine, measures seconds/pass,
 rows/s and model-rows/s, derives achieved GB/s from the engine's modeled HBM
-byte traffic, and compares against a bandwidth-roofline estimate (default
-TPU v5e 819 GB/s per chip — override with ``--hbm-peak-gbps`` or the
-``REPRO_HBM_PEAK_GBPS`` env var for TPU-measured runs; on the CPU interpret
-backend the roofline fraction is reported for trend only).
+byte traffic, and compares against a bandwidth-roofline estimate (the
+device's published HBM peak from ``peaks.py``, keyed by ``device_kind`` — a
+device without one is an error unless ``--hbm-peak-gbps`` or the
+``REPRO_HBM_PEAK_GBPS`` env var names a peak; on the CPU interpret backend
+no number it prints is a device metric).
 
 The modeled bytes encode the engine's central claim: the stream is read ONCE
 per fit regardless of how many bank tiles revisit it (``stream_passes`` stays
@@ -63,21 +64,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+try:  # run as a script: benchmarks/ is on sys.path
+    from peaks import peaks_for
+except ImportError:  # imported as benchmarks.<harness> (run.py)
+    from benchmarks.peaks import peaks_for
 from repro.kernels import streamsvm_fit_many
 from repro.kernels.ops import bank_tiling, engine_vmem_bytes
+from repro.runtime.compile_cache import use_compile_cache
 
 SCHEMA = "streamsvm-bench-engine/v4"
-DEFAULT_HBM_PEAK_GBPS = 819.0  # TPU v5e, per chip
 _DTYPE_BYTES = {"f32": 4, "bf16": 2}
 
 
 def hbm_peak_gbps(override=None) -> float:
-    """Roofline peak: --hbm-peak-gbps flag > REPRO_HBM_PEAK_GBPS env >
-    the TPU v5e default — so TPU-measured runs never need a source edit."""
+    """Roofline peak: --hbm-peak-gbps flag > REPRO_HBM_PEAK_GBPS env > the
+    published peak of the device JAX runs on (``peaks.py``, keyed by
+    ``device_kind``). A device without a published peak is an error."""
     if override is not None:
         return float(override)
     env = os.environ.get("REPRO_HBM_PEAK_GBPS")
-    return float(env) if env else DEFAULT_HBM_PEAK_GBPS
+    if env:
+        return float(env)
+    return peaks_for(jax.devices()[0].device_kind)["hbm_gbps"]
 
 
 # Keys every result row must carry — CI validates the emitted JSON against
@@ -575,7 +583,7 @@ def main(argv=None):
     ap.add_argument(
         "--hbm-peak-gbps", type=float, default=None, metavar="GBPS",
         help="HBM roofline peak in GB/s (default: REPRO_HBM_PEAK_GBPS env "
-        f"var, else {DEFAULT_HBM_PEAK_GBPS} — TPU v5e per chip)",
+        "var, else the device's published peak from benchmarks/peaks.py)",
     )
     ap.add_argument(
         "--filter", default=None, metavar="SUBSTR",
@@ -591,6 +599,7 @@ def main(argv=None):
     )
     args = ap.parse_args(argv)
     interpret = None if args.interpret is None else args.interpret == "true"
+    use_compile_cache(Path(__file__).resolve().parent.parent)
 
     report = run(args.smoke, args.reps, interpret, name_filter=args.filter,
                  peak_gbps=args.hbm_peak_gbps)

@@ -12,7 +12,8 @@ Two entry points:
 ``fit_bank_sharded``  a BANK of B models per shard via the tiled multi-ball
                       Pallas engine — M stream shards x B models in ONE data
                       pass each, folded with the bank-vectorized merge
-                      (meb.fold_merge over the gathered (S, B, ...) stack).
+                      (meb.fold_merge over the gathered (S, B, ...) stack,
+                      outside the mesh program when called eagerly).
                       Ragged streams are padded with inert sign-0 rows, so
                       any N works on any shard count.
 ``fit_kernel_bank_sharded``
@@ -37,14 +38,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 public API (replication check kwarg renamed to check_vma)
-    from jax import shard_map as _shard_map
-    _CHECK_REP_KW = "check_vma"
-except ImportError:  # older jax: experimental location, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_REP_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 from .kernel_bank import KernelBank, _fit_kernel_bank
 from .meb import Ball, fold_merge, merge_banks, merge_kernel_banks
@@ -133,7 +130,7 @@ def fit_sharded(
         in_specs=(spec, spec),
         out_specs=jax.tree.map(lambda _: P(), Ball(0, 0, 0, 0)),
         # scalar ball carries are constant-initialized per shard
-        **{_CHECK_REP_KW: False},
+        check_vma=False,
     )
     X = jax.device_put(X, NamedSharding(mesh, P(axes)))
     y = jax.device_put(y, NamedSharding(mesh, P(axes)))
@@ -143,17 +140,18 @@ def fit_sharded(
 @partial(
     jax.jit,
     static_argnames=(
-        "mesh", "axes", "n_shards", "shard_n", "n_rows", "variant",
-        "lookahead", "block_n", "b_tile", "stream_dtype", "bank_resident",
-        "interpret",
+        "mesh", "axes", "variant", "lookahead", "block_n", "b_tile",
+        "stream_dtype", "bank_resident", "interpret",
     ),
 )
-def _sharded_fold(
+def _sharded_fits(
     X, Y, cs, *,
-    mesh, axes, n_shards, shard_n, n_rows, variant, lookahead, block_n,
-    b_tile, stream_dtype, bank_resident, interpret,
+    mesh, axes, variant, lookahead, block_n, b_tile, stream_dtype,
+    bank_resident, interpret,
 ):
-    """jit'd shard_map core of fit_bank_sharded.
+    """jit'd shard_map core of fit_bank_sharded: one bank fit per shard,
+    gathered into an (S, B, ...) stack replicated on every device, NO
+    fold.
 
     Module-level so repeated calls with the same (shapes, mesh, config) hit
     the jit cache instead of rebuilding and re-tracing the shard_map closure
@@ -163,29 +161,22 @@ def _sharded_fold(
     def local_fit(Xs, Ys, cs_):
         from repro.kernels.ops import streamsvm_fit_many  # lazy: module cycle
 
-        # Shards whose whole contiguous range is padding produce a
-        # placeholder ball; mask them out of the fold so padding never
-        # changes results. A trace-time constant: every quantity is static.
-        live = jnp.arange(n_shards) * shard_n < n_rows
         bank = streamsvm_fit_many(
             Xs, Ys, cs_, None,
             variant=variant, lookahead=lookahead, block_n=block_n,
             b_tile=b_tile, stream_dtype=stream_dtype,
             bank_resident=bank_resident, interpret=interpret,
         )
-        gather = lambda v: jax.lax.all_gather(v, axes, tiled=False)
-        stacked = Ball(
-            w=gather(bank.w), r=gather(bank.r),
-            xi2=gather(bank.xi2), m=gather(bank.m),
-        )  # (S, B, ...) on every shard
-        return fold_merge(stacked, live=live)
+        return jax.tree.map(
+            lambda v: jax.lax.all_gather(v, axes, tiled=False), bank
+        )
 
     fn = _shard_map(
         local_fit,
         mesh=mesh,
         in_specs=(P(axes), P(None, axes), P()),
         out_specs=jax.tree.map(lambda _: P(), Ball(0, 0, 0, 0)),
-        **{_CHECK_REP_KW: False},
+        check_vma=False,
     )
     return fn(X, Y, cs)
 
@@ -205,7 +196,7 @@ def _sharded_kernel_fold(
 ):
     """jit'd shard_map core of fit_kernel_bank_sharded.
 
-    Module-level for the same jit-cache reason as ``_sharded_fold``. Each
+    Module-level for the same jit-cache reason as ``_sharded_fits``. Each
     shard runs the kernelized engine over its contiguous range (the engine's
     DEFERRED seeding makes ranges starting with inert sign-0 rows — or
     entirely padding — correct without special-casing), rewrites its
@@ -251,7 +242,7 @@ def _sharded_kernel_fold(
         mesh=mesh,
         in_specs=(P(axes), P(None, axes), P(), P()),
         out_specs=jax.tree.map(lambda _: P(), KernelBank(*range(7))),
-        **{_CHECK_REP_KW: False},
+        check_vma=False,
     )
     return fn(X, Y, cs, gamma)
 
@@ -371,7 +362,7 @@ def _sharded_kernel_shards(
         mesh=mesh,
         in_specs=(P(axes), P(None, axes), P(), P()),
         out_specs=jax.tree.map(lambda _: P(), KernelBank(*range(7))),
-        **{_CHECK_REP_KW: False},
+        check_vma=False,
     )
     return fn(X, Y, cs, gamma)
 
@@ -467,10 +458,12 @@ def fit_bank_sharded(
     ``stream_dtype="bf16"`` and ``bank_resident`` all apply per shard: each
     device holds its own bank copy, so residency is a per-shard decision
     and "auto" resolves identically on every shard) over its local range, the
-    per-shard (B, D) banks are exchanged with one all_gather, and every
-    model lane is folded with the Sec-4.3 merge (``meb.fold_merge`` over the
-    (S, B, ...) stack). Total data movement: each stream row is read from
-    HBM exactly once, on exactly one shard.
+    per-shard (B, D) banks are gathered, and every model lane is folded
+    with the Sec-4.3 merge (``meb.fold_merge`` over the (S, B, ...) stack).
+    Called eagerly, the fold runs on one device, outside the mesh program,
+    so the result is bit-identical to single-device fits of the same ranges
+    folded in order (``shard_ranges``) on any backend. Total data movement:
+    each stream row is read from HBM exactly once, on exactly one shard.
 
     X: (N, D) stream, Y: (B, N) per-model sign rows, cs: scalar or (B,)
     per-model C (traced). ``N % n_shards != 0`` is fine: the remainder is
@@ -514,13 +507,29 @@ def fit_bank_sharded(
     if not isinstance(X, jax.core.Tracer):  # eager call: place shards up front
         X = jax.device_put(X, NamedSharding(mesh, P(axes)))
         Y = jax.device_put(Y, NamedSharding(mesh, P(None, axes)))
-    folded = _sharded_fold(
+    stacked = _sharded_fits(
         X, Y, cs,
-        mesh=mesh, axes=axes, n_shards=n_shards, shard_n=shard_n, n_rows=n,
-        variant=variant, lookahead=lookahead, block_n=block_n, b_tile=b_tile,
-        stream_dtype=stream_dtype, bank_resident=bank_resident,
-        interpret=interpret,
+        mesh=mesh, axes=axes, variant=variant, lookahead=lookahead,
+        block_n=block_n, b_tile=b_tile, stream_dtype=stream_dtype,
+        bank_resident=bank_resident, interpret=interpret,
     )
+    # Shards whose whole range is padding (a suffix) stay out of the fold.
+    n_live = -(-n // shard_n)
+    if isinstance(stacked.w, jax.core.Tracer):
+        folded = fold_merge(jax.tree.map(lambda v: v[:n_live], stacked))
+    else:
+        # Eager call: fold the per-shard banks off the mesh, on the default
+        # device, with the same eager fold per-range single-device fits are
+        # folded with; then replicate. Compiled into the mesh program, XLA
+        # may fuse the merge arithmetic differently, which on a TPU moves
+        # the last bits of the result. The host hop copies bits exactly.
+        one = jax.tree.map(
+            lambda v: jax.device_put(np.asarray(v)[:n_live]), stacked
+        )
+        folded = jax.tree.map(
+            lambda v: jax.device_put(v, NamedSharding(mesh, P())),
+            fold_merge(one),
+        )
     if balls is not None:
         # The prior bank saw a disjoint (earlier) slice of the stream, so it
         # merges exactly like one more shard.

@@ -22,6 +22,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Mosaic's default f32 matmul rounds its operands to bf16 (a relative error
+# near 2**-9, seen on a v5e against the f32 host reference); every dot in
+# this kernel asks for full f32, the precision its references compute in.
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def _kernel(a_ref, b_ref, an_ref, bn_ref, g_ref, o_ref, acc_ref, *, epilogue: str):
     k_step = pl.program_id(2)
@@ -32,7 +37,7 @@ def _kernel(a_ref, b_ref, an_ref, bn_ref, g_ref, o_ref, acc_ref, *, epilogue: st
 
     acc_ref[...] += jax.lax.dot_general(
         a_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=_F32, preferred_element_type=jnp.float32,
     )
 
     @pl.when(k_step == pl.num_programs(2) - 1)
@@ -56,15 +61,13 @@ def gram_pallas(
     bn: int = 256,
     bk: int = 512,
     out_dtype=jnp.float32,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """K = epilogue(A B^T). A: (M, D), B: (N, D) — pre-padded by ops.py.
 
     ``gamma`` may be a python float or a traced scalar: it enters the grid
     as a (1, 1) f32 operand, so it never forces a recompile.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     m, d = A.shape
     n, d2 = B.shape
     if d != d2 or m % bm or n % bn or d % bk:

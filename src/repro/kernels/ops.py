@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels (padding, dtype policy).
 
 These are the entry points the rest of the framework uses; they handle
-128-alignment padding, interpret-mode selection (CPU container vs real TPU),
-bank tiling (`b_tile`), the stream dtype policy, and state packing.
-Semantics match ref.py exactly (tests sweep shapes and dtypes).
+128-alignment padding, interpret-mode selection (``resolve_interpret``: the
+one place it is decided), bank tiling (`b_tile`), the stream dtype policy,
+and state packing. Semantics match ref.py exactly (tests sweep shapes and
+dtypes).
 
 Dtype policy
 ------------
@@ -35,9 +36,10 @@ and lookahead windows) while the grid runs:
           working set is O(b_tile * D), independent of B;
   "auto"  picks from the per-step VMEM byte model (``engine_vmem_bytes`` /
           ``predict_vmem_bytes``) against a budget: the default
-          ``DEFAULT_VMEM_BUDGET_BYTES`` (16 MiB — the guide number for a
-          TPU core), overridable per call (``vmem_budget_bytes=``) or per
-          process (``REPRO_VMEM_BUDGET_BYTES``).
+          ``DEFAULT_VMEM_BUDGET_BYTES`` (16 MiB — the scoped VMEM limit a
+          Mosaic kernel gets by default on v5e; no kernel here raises it),
+          overridable per call (``vmem_budget_bytes=``) or per process
+          (``REPRO_VMEM_BUDGET_BYTES``).
 
 Configs that fit NO residency (e.g. a single (b_tile, D) ring slot already
 beyond the budget) are rejected up front with a ValueError carrying the
@@ -56,7 +58,11 @@ import jax.numpy as jnp
 from repro.core.meb import Ball
 from .gram import gram_pallas
 from .predict import NEG_MASK, predict_bank_pallas
-from .streamsvm_scan import streamsvm_scan_many_pallas, streamsvm_scan_pallas
+from .streamsvm_scan import (
+    D_CHUNK,
+    streamsvm_scan_many_pallas,
+    streamsvm_scan_pallas,
+)
 
 _STREAM_DTYPES = {
     None: None,
@@ -65,6 +71,27 @@ _STREAM_DTYPES = {
     "bf16": jnp.bfloat16,
     "bfloat16": jnp.bfloat16,
 }
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Decide whether the Pallas kernels run in interpret mode.
+
+    The kernel modules take ``interpret`` as given; every public wrapper
+    here resolves it through this function. ``None`` means interpret mode
+    exactly when the default backend is not a TPU (the CPU test host). On a
+    TPU backend every kernel compiles with Mosaic: asking for interpret mode
+    there is a ValueError, so no path can hide the device behind the
+    interpreter.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU backend: the kernels compile with "
+            "Mosaic there; pass interpret=None"
+        )
+    return bool(interpret)
 
 
 def _resolve_stream_dtype(stream_dtype):
@@ -80,13 +107,16 @@ def _resolve_stream_dtype(stream_dtype):
 
 
 def bank_tiling(b: int, b_tile: int | None):
-    """Resolve the engine's bank tiling for B models.
+    """Resolve the engines' bank tiling for B models.
 
     Returns ``(effective_b_tile, n_bank_tiles)``: the requested tile rounded
     up to the f32 sublane multiple of 8 (default: one tile holding the whole
-    bank) and the number of tiles covering the (padded) bank. The single
-    source of truth for this policy — the throughput harness derives its
-    modeled tile counts from here too.
+    bank) and the number of tiles covering the (padded) bank. Every tile
+    the engines stage is then a sublane slab at an 8-aligned row offset,
+    which Mosaic takes for any such size; the predict engine's outputs are
+    laid out so their tiles are legal too (see ``predict_bank_pallas``).
+    The single source of truth for this policy — the throughput harnesses
+    derive their modeled tile counts from here too.
     """
     bt = -(-b // 8) * 8 if b_tile is None else -(-b_tile // 8) * 8
     return bt, -(-b // bt)
@@ -115,9 +145,11 @@ def ovr_group_tiling(b: int, n_classes: int, b_tile: int | None):
     group's argmax never crosses a bank tile. Returns ``(nc_pad, g_tile,
     padded_groups)``: lanes per padded group, groups per bank tile (derived
     from the requested lane ``b_tile``; default one tile holding every
-    group), and the group count padded to a whole number of tiles. The
-    single source of truth for this policy — the serving throughput harness
-    derives its modeled tile counts from here too.
+    group), and the group count padded to a whole number of tiles. Any
+    g_tile compiles: the kernel writes each tile's (q_block, g_tile) result
+    into an output plane of its own. The single source of truth for this
+    policy — the serving throughput harness derives its modeled tile counts
+    from here too.
     """
     g = b // n_classes
     nc_pad = -(-n_classes // 8) * 8
@@ -130,8 +162,9 @@ def ovr_group_tiling(b: int, n_classes: int, b_tile: int | None):
 # ---------------------------------------------------------------------------
 
 #: Default per-step VMEM budget for the "auto" residency policy (and the
-#: preflight check). ~16 MiB is the classic per-core figure; real parts vary,
-#: so it is overridable per call (``vmem_budget_bytes=``) and per process
+#: preflight check): the 16 MiB of scoped VMEM a Mosaic kernel gets by
+#: default on v5e (no pallas_call here sets compiler params). Overridable
+#: per call (``vmem_budget_bytes=``) and per process
 #: (``REPRO_VMEM_BUDGET_BYTES``).
 DEFAULT_VMEM_BUDGET_BYTES = 16 * 2**20
 
@@ -151,6 +184,18 @@ def _stream_bytes(stream_dtype) -> int:
     return 2 if dt == jnp.bfloat16 else 4
 
 
+#: (b_tile, 1) per-model columns the engine's row loop keeps live at once
+#: (r, xi2, wsq, m, decay, c_inv, gain and their temporaries). Mosaic holds
+#: each in (8, 128) tiles, so one costs b_tile * 128 * 4 bytes of VMEM. The
+#: count is calibrated against the scoped VMEM the v5e compiler allocates
+#: (measured by compiling under ``vmem_limit_bytes``) for the quickstart
+#: bank (B = 600, D = 784, N = 65,536) at b_tile 256, 512 and 600 and the
+#: beyond-VMEM bank (B = 3000, D = 4096, N = 16,384) at b_tile 64 and 128:
+#: with it the model is at or above the compiler wherever it is near the
+#: budget, so the tile it derives compiles.
+ROW_LOOP_COLUMNS = 35
+
+
 def engine_vmem_bytes(
     b: int,
     d: int,
@@ -163,36 +208,38 @@ def engine_vmem_bytes(
 ) -> dict:
     """Per-step VMEM working set of the training engine, bytes by term.
 
-    Models the padded shapes the kernel actually allocates (D to the lane
-    multiple of 128, B to whole bank tiles, tiles to the sublane multiple of
-    8). BlockSpec-delivered tiles count twice — Pallas double-buffers its
-    own pipeline — and so do the explicit 2-slot rings of the HBM-resident
-    layout. The "auto" policy and the preflight ValueError both read this;
-    the BENCH harnesses record its total per row as
-    ``vmem_working_set_bytes``.
+    Models the padded shapes the kernel allocates (D to the lane multiple
+    of 128, B to whole bank tiles, per-model arrays to 128-lane rows):
+    the double-buffered stream and sign tiles (plus the signs' f32 values),
+    the bf16 parts Mosaic splits a full-f32 dot's operands into (one
+    ``D_CHUNK``-wide chunk of the stream tile and of the bank tile at a
+    time), the block Gram scratch, the per-model parameter tile, the row
+    loop's per-model columns, and the VMEM slots of the bank, its state
+    slabs and the lookahead windows — one slot per bank tile when
+    VMEM-resident, two when the tiles ring through from HBM. The "auto"
+    policy and the preflight ValueError both read this; the BENCH harnesses
+    record its total per row as ``vmem_working_set_bytes``.
     """
     sz = _stream_bytes(stream_dtype)
     bt, n_tiles = bank_tiling(b, b_tile)
-    bp = bt * n_tiles
     dp = -(-d // 128) * 128
-    L = lookahead_max
-    state_rows = 4 + 1 + (1 if L else 0)  # st rows + m + cnt (lanes x 4B)
-    out = {
+    L = lookahead_max or 0
+    slots = n_tiles if bank_resident == "vmem" else min(2, n_tiles)
+    lane_row = 128 * 4  # one per-model row of a (rows, 128) f32/i32 slab
+    f32_copy = 4 if sz != 4 else 0  # bf16 tiles are upcast to f32 values
+    return {
         "stream_tile": 2 * block_n * dp * sz,
-        "sign_tile": 2 * bt * block_n * sz,
-        # per-tile params in + outputs out (w0/w, scalars, m, gain, L), all
-        # staged through the BlockSpec pipeline (x2)
-        "params_io": 2 * (2 * bt * dp + 2 * bt * 4 + 3 * bt) * 4,
+        "sign_tile": 2 * bt * block_n * sz + bt * block_n * f32_copy,
+        # three bf16 parts (6 bytes) per element of both dot operands
+        "dot_split": 6 * (block_n + bt) * min(dp, D_CHUNK),
+        "gram": block_n * block_n * 4,
+        "params": 2 * bt * lane_row,
+        "row_loop": ROW_LOOP_COLUMNS * bt * lane_row,
+        "bank": slots * bt * dp * 4,
+        "state": slots * (3 if L else 2) * bt * lane_row,
+        # window slots, plus one window-sized temporary of the push/flush
+        "lookahead": (slots + 1) * L * bt * dp * 4,
     }
-    if bank_resident == "vmem":
-        out["bank"] = bp * dp * 4
-        out["state"] = state_rows * bp * 4
-        out["lookahead"] = bp * L * dp * 4 if L else 0
-    else:
-        out["bank"] = 2 * bt * dp * 4  # 2-slot ring
-        out["state"] = 2 * state_rows * bt * 4
-        out["lookahead"] = 2 * bt * L * dp * 4 if L else 0
-    return out
 
 
 def kernel_engine_vmem_bytes(
@@ -280,27 +327,28 @@ def predict_vmem_bytes(
     out = {
         "query_tile": 2 * q_block * dp * sz,
         "bank": 2 * bt * dp * 4,  # BlockSpec pipeline or 2-slot ring: same
-        "bias": 2 * bt * 4,
+        "bias": 2 * bt * 128 * 4,  # (b_tile, 1) rows pad to 128 lanes
         "epilogue_state": (2 * q_block * k * 4 if epilogue == "topk" else 0),
         "out_tiles": 2 * q_block * out_cols * 4,
     }
     return out
 
 
-def derive_hbm_b_tile(b: int, byte_model_at, *, vmem_budget: int):
-    """Pick a ring tile for an HBM-resident bank when the caller gave none.
+def derive_b_tile(b: int, byte_model_at, *, vmem_budget: int):
+    """Pick a bank tile for one residency when the caller gave none.
 
-    The default ``b_tile=None`` means "one tile holding the whole bank" —
-    the right default VMEM-resident, but self-defeating HBM-resident (the
-    2-slot ring would be twice the bank). ``byte_model_at(b_tile)`` returns
-    the hbm working-set breakdown for a candidate tile; this returns the
-    largest power-of-two tile (512 down to 8) under the budget, or the
-    whole bank if even that fits, so ``bank_resident="auto"``/``"hbm"``
-    work on beyond-VMEM banks without the caller hand-picking a tile. A
+    The default ``b_tile=None`` means "one tile holding the whole bank".
+    The engines' per-step working set grows with the tile (an HBM ring
+    holds two of them; every tile's row loop holds per-model columns), so a
+    large bank can be over the budget as one tile and fit as several.
+    ``byte_model_at(b_tile)`` returns one residency's working-set breakdown
+    for a candidate tile; this returns None if the whole bank fits as one
+    tile, else the largest power-of-two tile (512 down to 8) under the
+    budget, or 8 when nothing fits (the preflight then raises). A
     caller-supplied ``b_tile`` is never overridden.
     """
     if sum(byte_model_at(None).values()) <= vmem_budget:
-        return None  # the whole bank rings within budget — keep one tile
+        return None  # the whole bank fits as one tile — keep it
     for cand in (512, 256, 128, 64, 32, 16, 8):
         if cand < b and sum(byte_model_at(cand).values()) <= vmem_budget:
             return cand
@@ -353,6 +401,58 @@ def resolve_bank_resident(
     return bank_resident, by
 
 
+# vmem_budget_bytes is shadowed inside plan_bank_engine and the jit'd
+# wrappers, whose keyword arguments reuse the public name.
+_vmem_budget = vmem_budget_bytes
+
+
+def plan_bank_engine(
+    b: int,
+    d: int,
+    *,
+    block_n: int = 256,
+    b_tile: int | None = None,
+    stream_dtype=None,
+    lookahead_max: int | None = None,
+    bank_resident: str = "auto",
+    vmem_budget_bytes: int | None = None,
+) -> tuple[str, int | None]:
+    """The training engine's ``(residency, b_tile)`` for one configuration.
+
+    ``b_tile=None`` means "whole bank in one tile". When the caller named no
+    tile, one that fits the budget is derived: VMEM-resident first (under
+    "auto"), then as an HBM ring, so "auto" rescues beyond-VMEM banks. Then
+    the residency preflight resolves "auto" and rejects configs whose
+    per-step VMEM working set fits no residency — before Pallas gets a
+    chance to fail opaquely inside lowering (it guards forced "vmem" too).
+    ``streamsvm_fit_many`` trains with exactly this plan.
+    """
+    budget = _vmem_budget(vmem_budget_bytes)
+    bytes_at = lambda bt_, res: engine_vmem_bytes(
+        b, d, block_n=block_n, b_tile=bt_, stream_dtype=stream_dtype,
+        lookahead_max=lookahead_max, bank_resident=res,
+    )
+    if b_tile is None and bank_resident in _BANK_RESIDENCIES:
+        order = ("vmem", "hbm") if bank_resident == "auto" else (bank_resident,)
+        for res in order:
+            b_tile = derive_b_tile(
+                b, lambda bt_: bytes_at(bt_, res), vmem_budget=budget
+            )
+            if sum(bytes_at(b_tile, res).values()) <= budget:
+                break
+    residency, _ = resolve_bank_resident(
+        bank_resident,
+        lambda res: bytes_at(b_tile, res),
+        vmem_budget=budget,
+        what="streamsvm_fit_many",
+        shapes=(
+            f"B={b}, D={d}, block_n={block_n}, b_tile={b_tile}, "
+            f"lookahead_max={lookahead_max}, stream_dtype={stream_dtype!r}"
+        ),
+    )
+    return residency, b_tile
+
+
 def _pad_to(x, mult, axis):
     size = x.shape[axis]
     pad = (-size) % mult
@@ -398,14 +498,9 @@ def streamsvm_fit(
     w0p = _pad_to(w0.astype(jnp.float32), 128, 0)
     w, r, xi2, m = streamsvm_scan_pallas(
         Xp, yp, w0p, r0, xi20, c_inv, m0,
-        n_valid=n, block_n=block_n, interpret=interpret,
+        n_valid=n, block_n=block_n, interpret=resolve_interpret(interpret),
     )
     return Ball(w=w[:d], r=r, xi2=xi2, m=m)
-
-
-# The residency helpers below shadow their module-level names inside the
-# jit'd wrappers (whose keyword arguments reuse the public names).
-_vmem_budget = vmem_budget_bytes
 
 
 @partial(
@@ -502,34 +597,10 @@ def streamsvm_fit_many(
                 f"got {lookahead} for B={b}"
             )
     l_max = max(lookahead) if is_lookahead else None
-    budget = _vmem_budget(vmem_budget_bytes)
-    engine_bytes_at = lambda bt_, res: engine_vmem_bytes(
-        b, d, block_n=block_n, b_tile=bt_, stream_dtype=stream_dtype,
-        lookahead_max=l_max, bank_resident=res,
-    )
-    # b_tile=None means "whole bank in one tile" — right VMEM-resident,
-    # self-defeating as a ring slot. When residency is (or may resolve to)
-    # hbm and the caller named no tile, derive one that fits the budget so
-    # "auto" genuinely rescues beyond-VMEM banks.
-    if b_tile is None and bank_resident in ("auto", "hbm"):
-        vmem_fits = sum(engine_bytes_at(None, "vmem").values()) <= budget
-        if bank_resident == "hbm" or not vmem_fits:
-            b_tile = derive_hbm_b_tile(
-                b, lambda bt_: engine_bytes_at(bt_, "hbm"),
-                vmem_budget=budget,
-            )
-    # Residency preflight: resolve "auto" and reject configs whose per-step
-    # VMEM working set cannot fit under ANY residency — BEFORE Pallas gets a
-    # chance to fail opaquely inside lowering (also guards forced "vmem").
-    residency, _ = resolve_bank_resident(
-        bank_resident,
-        lambda res: engine_bytes_at(b_tile, res),
-        vmem_budget=budget,
-        what="streamsvm_fit_many",
-        shapes=(
-            f"B={b}, D={d}, block_n={block_n}, b_tile={b_tile}, "
-            f"lookahead_max={l_max}, stream_dtype={stream_dtype!r}"
-        ),
+    residency, b_tile = plan_bank_engine(
+        b, d, block_n=block_n, b_tile=b_tile, stream_dtype=stream_dtype,
+        lookahead_max=l_max, bank_resident=bank_resident,
+        vmem_budget_bytes=vmem_budget_bytes,
     )
     if balls is None:
         w0 = Y[:, 0:1] * X[0][None, :]
@@ -583,7 +654,7 @@ def streamsvm_fit_many(
         b_tile=bt,
         stream_dtype=stream_dtype,
         bank_resident=residency,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return Ball(w=W[:b, :d], r=r[:b], xi2=xi2[:b], m=m[:b])
 
@@ -621,7 +692,7 @@ def gram(
     Bp = _pad_to(_pad_to(B.astype(jnp.float32), bk, 1), bn_, 0)
     out = gram_pallas(
         Ap, Bp, epilogue=epilogue, gamma=gamma, bm=bm_, bn=bn_, bk=bk,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return out[:m, :n]
 
@@ -737,7 +808,7 @@ def predict_bank(
     if bank_resident == "hbm" and b_tile is None:
         # default "whole bank per tile" is self-defeating as a ring slot —
         # derive a budget-fitting tile (a caller's b_tile is never touched)
-        b_tile = derive_hbm_b_tile(
+        b_tile = derive_b_tile(
             b, lambda bt_: predict_bytes_at(bt_, "hbm"), vmem_budget=budget
         )
     residency, _ = resolve_bank_resident(
@@ -750,6 +821,7 @@ def predict_bank(
             f"epilogue={epilogue!r}, stream_dtype={stream_dtype!r}"
         ),
     )
+    interpret = resolve_interpret(interpret)
     Xp = _pad_to(_pad_to(X.astype(jnp.float32), 128, 1), q_block, 0)
     if stream_dtype is not None:
         Xp = Xp.astype(stream_dtype)

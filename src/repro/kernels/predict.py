@@ -37,7 +37,7 @@ Bank residency (``bank_resident``) mirrors the training engine's knob:
 
   "vmem"  bank tiles are BlockSpec-delivered — Pallas's automatic pipeline
           stages each (b_tile, D) slice into VMEM (the PR 4 layout).
-  "hbm"   the bank stays in an ANY/HBM-space ref and the kernel streams
+  "hbm"   the bank stays in an HBM ref and the kernel streams
           (b_tile, D) slices through a 2-slot VMEM ring with
           ``pltpu.make_async_copy`` — the prefetch of grid step t+1's tile
           issued before compute on step t's slot, DMA semaphores in scratch.
@@ -56,6 +56,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# Mosaic's default f32 matmul rounds its operands to bf16 (a relative error
+# near 2**-9, seen on a v5e against the f32 host reference); every dot in
+# this kernel asks for full f32, the precision its references compute in.
+_F32 = jax.lax.Precision.HIGHEST
 
 # Large-but-finite lane mask: padded bank lanes carry this additive bias so
 # every real margin beats them (finite so bias + margin never becomes NaN).
@@ -78,7 +83,7 @@ def _first_argmax(vals: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def _kernel(
     q_ref,  # (q_block, D) query tile (f32 or bf16)
-    w_ref,  # (b_tile, D) bank tile (f32) — or the full ANY-space bank (hbm)
+    w_ref,  # (b_tile, D) bank tile (f32) — or the full HBM bank (hbm)
     bias_ref,  # (b_tile, 1) additive lane bias: 0 live, NEG_MASK padded
     *refs,  # epilogue outputs, then scratch (topk adds 2; hbm adds ring+sem)
     epilogue: str,
@@ -138,7 +143,7 @@ def _kernel(
     q = q_ref[...].astype(jnp.float32)  # bf16 query tiles upcast here
     s = jax.lax.dot_general(
         q, w_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=_F32, preferred_element_type=jnp.float32,
     )  # (q_block, b_tile) margins
 
     if epilogue == "scores":
@@ -204,7 +209,7 @@ def predict_bank_pallas(
     nc_pad: int | None = None,
     k: int | None = None,
     bank_resident: str = "vmem",
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Score padded queries against a padded bank with a fused epilogue.
 
@@ -221,12 +226,10 @@ def predict_bank_pallas(
       "topk"   -> ((Qn, k) f32, (Qn, k) int32) per-query top-k model scores
                   and ids, descending (running VMEM scratch across tiles)
 
-    ``bank_resident="hbm"`` keeps W in ANY/HBM memory and double-buffers
+    ``bank_resident="hbm"`` keeps W in HBM and double-buffers
     (b_tile, D) slices through a 2-slot VMEM ring (see module docstring);
     bit-exact with the default BlockSpec-delivered layout.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if bank_resident not in ("vmem", "hbm"):
         raise ValueError(
             f"unknown bank_resident {bank_resident!r}; expected 'vmem' or "
@@ -278,27 +281,40 @@ def predict_bank_pallas(
         # query tile index ignores j -> DMA'd once, resident across the bank
         pl.BlockSpec((q_block, d), lambda i, j: (i, 0)),
         # hbm: the bank never enters the BlockSpec pipeline — the kernel
-        # rings (b_tile, D) slices out of ANY space itself
-        pl.BlockSpec(memory_space=pltpu.ANY)
+        # rings (b_tile, D) slices out of HBM itself
+        pl.BlockSpec(memory_space=pltpu.HBM)
         if hbm
         else pl.BlockSpec((b_tile, d), lambda i, j: (j, 0)),
         pl.BlockSpec((b_tile, 1), lambda i, j: (j, 0)),
     ]
     scratch = []
+    # A (q_block, cols) output tile that is a lane window of the full
+    # (Qn, bp) result must be 128 lanes wide for Mosaic. Narrower tiles get
+    # one (Qn, cols) output plane per bank tile instead, whose whole last
+    # dim the block then spans (legal for any width); the planes are
+    # interleaved back into (Qn, bp) order below.
+    n_planes = grid[1]
+    planes = n_planes > 1 and epilogue != "topk" and (
+        epilogue == "ovr" or b_tile % 128 != 0
+    )
+    cols = b_tile // nc_pad if epilogue == "ovr" else b_tile
+
+    def out_tile(dtype):
+        if planes:
+            return (
+                pl.BlockSpec((pl.Squeezed(), q_block, cols),
+                             lambda i, j: (j, i, 0)),
+                jax.ShapeDtypeStruct((n_planes, qn, cols), dtype),
+            )
+        return (
+            pl.BlockSpec((q_block, cols), lambda i, j: (i, j)),
+            jax.ShapeDtypeStruct((qn, n_planes * cols), dtype),
+        )
+
     if epilogue == "scores":
-        out_specs = [pl.BlockSpec((q_block, b_tile), lambda i, j: (i, j))]
-        out_shape = [jax.ShapeDtypeStruct((qn, bp), jnp.float32)]
+        out_specs, out_shape = zip(out_tile(jnp.float32))
     elif epilogue == "ovr":
-        g_tile = b_tile // nc_pad
-        gp = bp // nc_pad
-        out_specs = [
-            pl.BlockSpec((q_block, g_tile), lambda i, j: (i, j)),
-            pl.BlockSpec((q_block, g_tile), lambda i, j: (i, j)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((qn, gp), jnp.int32),
-            jax.ShapeDtypeStruct((qn, gp), jnp.float32),
-        ]
+        out_specs, out_shape = zip(out_tile(jnp.int32), out_tile(jnp.float32))
     else:  # topk: outputs parked at tile 0, written on the last bank tile
         out_specs = [
             pl.BlockSpec((q_block, k), lambda i, j: (i, 0)),
@@ -330,4 +346,6 @@ def predict_bank_pallas(
         scratch_shapes=scratch,
         interpret=interpret,
     )(Q, W.astype(jnp.float32), bias.astype(jnp.float32))
+    if planes:
+        outs = [o.transpose(1, 0, 2).reshape(qn, n_planes * cols) for o in outs]
     return outs[0] if epilogue == "scores" else tuple(outs)

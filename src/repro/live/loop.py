@@ -768,8 +768,8 @@ class LiveBank:
         dispatch. Returns the same (lo, bank) parts list as the degraded
         path — for kernel banks literally the per-shard fits (gathered,
         unfolded); for linear banks the mesh's folded bank as a single part
-        (``fit_bank_sharded``'s in-jit fold is bit-identical to the eager
-        fold, so both paths agree)."""
+        (``fit_bank_sharded`` folds its per-shard fits with the same eager
+        fold on one device, so both paths agree)."""
         if self.bank_kind == "kernel":
             kw = {k: v for k, v in self._engine_kw.items() if k != "seed_check"}
             stacked = fit_kernel_bank_shards(
@@ -1100,10 +1100,13 @@ def run_live_with_restarts(
     (programming errors) propagate immediately.
 
     The default policy classifies injected test failures, ``DeviceLostError``
-    and the JAX/XLA runtime's device-fault exceptions (e.g.
-    ``jaxlib.xla_extension.XlaRuntimeError``) as retryable
+    and the JAX runtime's device-fault exception
+    (``jax.errors.JaxRuntimeError``) as retryable
     (``runtime.default_live_retryable``): a transient device fault burns a
-    restart instead of propagating as if it were a programming error.
+    restart instead of propagating as if it were a programming error. A
+    Mosaic compile refusal or a device out-of-memory error raises the same
+    class, so under this policy it burns the restarts, recompiling each
+    time, before it surfaces: call ``live.run()`` directly to see it at once.
     """
     policy = policy or RetryPolicy(
         retryable=default_live_retryable(), max_retries=max_restarts

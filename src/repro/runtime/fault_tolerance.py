@@ -40,29 +40,13 @@ class DeviceLostError(RuntimeError):
 
 def runtime_device_errors() -> Tuple[Type[BaseException], ...]:
     """The exception classes the JAX/XLA runtime raises for device-level
-    faults (e.g. ``jaxlib.xla_extension.XlaRuntimeError`` for a lost or
-    wedged device). Import-guarded: on a build without jaxlib (stubbed CI,
-    docs env) this returns an empty tuple and callers degrade gracefully.
+    faults: ``jax.errors.JaxRuntimeError`` for a lost or wedged device.
+
+    It is also what a Mosaic compile refusal or a device out-of-memory
+    raises, so a policy built on it retries those too (see
+    ``default_live_retryable``).
     """
-    errs: List[Type[BaseException]] = []
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-
-        errs.append(XlaRuntimeError)
-    except Exception:
-        pass
-    try:
-        from jax.errors import JaxRuntimeError
-
-        errs.append(JaxRuntimeError)
-    except Exception:
-        pass
-    # newer jax aliases one onto the other; keep each class once
-    out: List[Type[BaseException]] = []
-    for e in errs:
-        if e not in out:
-            out.append(e)
-    return tuple(out)
+    return (jax.errors.JaxRuntimeError,)
 
 
 def default_live_retryable() -> Tuple[Type[BaseException], ...]:

@@ -326,3 +326,30 @@ def test_residency_switch_recompiles_c_sweep_does_not():
     streamsvm_fit_many(X, Y, jnp.full((b,), 1.0), block_n=64, b_tile=8,
                        bank_resident="vmem")  # residency switch: new entry
     assert streamsvm_fit_many._cache_size() == start + 2
+
+
+def test_quickstart_bank_trains_vmem_resident_by_default():
+    """The ROADMAP quickstart bank (200 classes x 3 C = 600 models at
+    D = 784) is over the default budget as one tile but fits VMEM-resident
+    in derived tiles, while the beyond-VMEM bank (3000 x 4096) needs HBM
+    residency with a derived ring tile — the two residencies chip_smoke.py
+    drives through "auto" and "hbm"."""
+    from repro.kernels.ops import derive_b_tile
+
+    budget = DEFAULT_VMEM_BUDGET_BYTES
+    small = lambda bt: engine_vmem_bytes(600, 784, b_tile=bt,
+                                         bank_resident="vmem")
+    bt = derive_b_tile(600, small, vmem_budget=budget)
+    assert bt is not None and sum(small(bt).values()) <= budget
+    assert resolve_bank_resident(
+        "auto", lambda res: engine_vmem_bytes(600, 784, b_tile=bt,
+                                              bank_resident=res),
+        vmem_budget=budget, what="t", shapes="s",
+    )[0] == "vmem"
+    big = lambda bt: engine_vmem_bytes(3000, 4096, b_tile=bt,
+                                       bank_resident="hbm")
+    bt = derive_b_tile(3000, big, vmem_budget=budget)
+    assert bt is not None and sum(big(bt).values()) <= budget
+    for bt in (None, 512, 256, 128, 64, 32, 16, 8):
+        assert sum(engine_vmem_bytes(3000, 4096, b_tile=bt,
+                                     bank_resident="vmem").values()) > budget

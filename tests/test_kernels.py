@@ -60,3 +60,18 @@ def test_streamsvm_kernel_continues_from_ball():
     b_full = streamsvm_fit(X, y, 5.0)
     np.testing.assert_allclose(np.asarray(b_rest.w), np.asarray(b_full.w), rtol=2e-4, atol=2e-5)
     assert int(b_rest.m) == int(b_full.m)
+
+
+def test_interpret_mode_is_resolved_in_one_place():
+    """ops.resolve_interpret decides interpret mode for every kernel: the
+    interpreter exactly off-TPU by default, and an explicit choice kept
+    where it is legal (on a TPU, interpret=True is refused)."""
+    import jax
+
+    from repro.kernels.ops import resolve_interpret
+
+    on_tpu = jax.default_backend() == "tpu"
+    assert resolve_interpret(None) is (not on_tpu)
+    assert resolve_interpret(False) is False
+    if not on_tpu:
+        assert resolve_interpret(True) is True

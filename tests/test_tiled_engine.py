@@ -65,6 +65,37 @@ def test_tiled_matches_bank_ref_at_8x_tile():
     np.testing.assert_array_equal(np.asarray(bank.m), np.asarray(m))
 
 
+@pytest.mark.parametrize("b,n,d,block_n,b_tile", [
+    (13, 301, 20, 24, 8),      # block_n not a power of two, ragged B
+    (21, 250, 33, 40, 16),     # two bank tiles, the second half padding
+    (7, 199, 9, 56, None),     # one tile of 8 rows holding 7 models
+])
+def test_row_reads_match_ref_with_inert_column(b, n, d, block_n, b_tile):
+    """The row loop reads row j of the block Gram, of the running inner
+    products and of the signs without slicing a value at the traced row
+    (sublane reads of refs, one-hot lane sums). Against the plain-jnp scan
+    at block sizes that are not powers of two, a ragged bank, and one stream
+    row whose sign is 0 for every model (inert); the two residencies stay
+    bit-exact with each other."""
+    X, Y, cs = _bank_data(b, n, d, seed=3 * b + n)
+    Y = Y.at[:, n // 2].set(0.0)
+    kw = dict(block_n=block_n, b_tile=b_tile)
+    vmem = streamsvm_fit_many(X, Y, cs, bank_resident="vmem", **kw)
+    hbm = streamsvm_fit_many(X, Y, cs, bank_resident="hbm", **kw)
+    for field in ("w", "r", "xi2", "m"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(hbm, field)), np.asarray(getattr(vmem, field))
+        )
+    c_inv = 1.0 / cs
+    W0 = Y[:, 0:1] * X[0][None, :]
+    w, r, xi2, m = streamsvm_scan_many_ref(
+        X[1:], Y[:, 1:], W0, 0.0, c_inv, c_inv, 1, gain=c_inv
+    )
+    np.testing.assert_allclose(np.asarray(vmem.w), np.asarray(w), rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(vmem.r), np.asarray(r), rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(vmem.m), np.asarray(m))
+
+
 def test_padded_model_rows_stay_inert():
     """B % b_tile != 0 pads model lanes; results must equal the unpadded run
     and contain no NaN/inf leakage from the padded lanes."""
