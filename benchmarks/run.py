@@ -51,12 +51,6 @@ def main() -> None:
     for r in fig3_lookahead.run(runs=8 if args.fast else 20):
         print(f'lookahead_L{r["L"]},{r["mean"]:.2f},acc% (std {r["std"]:.3f})')
 
-    _section("Streaming throughput / constant-memory claims")
-    from benchmarks import streaming_throughput
-
-    for name, val, unit in streaming_throughput.run():
-        print(f"{name},{val:.3f},{unit}")
-
     _section("Beyond-paper: multi-ball (Sec 4.3) + RBF kernelized (Sec 4.2)")
     from benchmarks import beyond
 

@@ -23,8 +23,8 @@ through the same ``BankServer`` as the linear bank —
 meta, and served scores are BIT-EXACT with the direct
 ``core.kernel_bank_decision`` readout (asserted below, not just printed).
 
-Throughput rows for this path live in BENCH_engine.json (kernel_* rows)
-and BENCH_serving.json (serve_kernel_* rows).
+Speed on a TPU is measured by the chip benchmark, ``benchmarks/chip/``;
+it has no kernel-bank cell yet.
 """
 import tempfile
 import time

@@ -23,10 +23,8 @@ Run the 8-device version of this flow (simulated host devices):
 
     PYTHONPATH=src python examples/svm_distributed.py
 
-Engine throughput numbers for these paths are tracked in BENCH_engine.json
-(including the ``n_shards`` scaling rows) — regenerate with:
-
-    PYTHONPATH=src python benchmarks/streaming_throughput.py
+Training speed on a TPU is measured by the chip benchmark,
+``benchmarks/chip/run.py`` (its cells are in ``BENCHMARK.json``).
 """
 import time
 
@@ -85,7 +83,7 @@ def main():
     # halve its HBM bytes, and B is no longer capped by the per-step VMEM
     # working set. Training 600 independent fits here would read the stream
     # 600 times; the bank reads it ONCE. (Scaled-down shapes so the CPU
-    # interpret mode stays fast; on TPU crank N/D and watch BENCH_engine.json.
+    # interpret mode stays fast; speed on a TPU is benchmarks/chip/'s job.
     # Note the per-model core-vector budget m stays O(log N) — the paper's
     # sparsity claim — so extreme-imbalance OVR argmax at 200 classes is a
     # stress test of Algorithm 1 itself, not of the engine; the engine is
@@ -115,8 +113,7 @@ def main():
         print(f"  C={cval:6.1f}  core vectors/model: "
               f"min={mc.min()} mean={mc.mean():.1f} max={mc.max()}")
     print(f"bank state O(B*D) = {ovr.w.nbytes} bytes vs one stream read "
-          f"of {Xm.nbytes} bytes; throughput harness: "
-          "PYTHONPATH=src python benchmarks/streaming_throughput.py")
+          f"of {Xm.nbytes} bytes; chip benchmark: benchmarks/chip/")
 
     # --- the same bank, HBM-resident ----------------------------------------
     # bank_resident="hbm" lifts the VMEM cap on B*D: the bank stays in HBM

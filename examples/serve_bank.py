@@ -11,9 +11,8 @@ predict kernel (per-C-grid-group argmax epilogue). Served f32 results are
 BIT-EXACT with the direct jnp readout (core.predict_c_grid) — asserted
 below, not just printed.
 
-Serving throughput numbers for this path are tracked in BENCH_serving.json:
-
-    PYTHONPATH=src python benchmarks/serving_throughput.py
+Serving latency on a TPU is measured by the chip benchmark,
+``benchmarks/chip/run.py`` (its cells are in ``BENCHMARK.json``).
 """
 import tempfile
 import time

@@ -106,8 +106,8 @@ def main():
               f"core vectors/model: min={mc.min()} mean={mc.mean():.1f} "
               f"max={mc.max()}")
     print(f"  merged bank state O(B*D) = {ovr.w.nbytes} bytes, replicated on "
-          f"all {len(jax.devices())} devices; throughput rows: "
-          "PYTHONPATH=src python benchmarks/streaming_throughput.py")
+          f"all {len(jax.devices())} devices; chip benchmark: "
+          "benchmarks/chip/")
 
 
 if __name__ == "__main__":

@@ -97,4 +97,5 @@ def gram_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="gram",
     )(A, B, an, bn_, g)

@@ -345,6 +345,7 @@ def predict_bank_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="predict_bank",
     )(Q, W.astype(jnp.float32), bias.astype(jnp.float32))
     if planes:
         outs = [o.transpose(1, 0, 2).reshape(qn, n_planes * cols) for o in outs]

@@ -639,6 +639,7 @@ def streamsvm_scan_pallas(
             pltpu.VMEM((block_n, block_n), jnp.float32),
         ],
         interpret=interpret,
+        name="streamsvm_scan",
     )(X.astype(jnp.float32), y.reshape(n, 1).astype(jnp.float32), w0, s0, nv)
     return w_out[0], s_out[0, 0], s_out[0, 1], s_out[0, 3].astype(jnp.int32)
 
@@ -786,6 +787,7 @@ def streamsvm_scan_many_pallas(
         ],
         input_output_aliases={4 + a: a for a in range(n_arrays)},
         interpret=interpret,
+        name="streamsvm_scan_many",
     )(X.astype(stream_dtype), Y.astype(stream_dtype), params, nv, *state)
     w_out, st_out, m_out = outs[0], outs[1], outs[2]
     return w_out, st_out[:, 0], st_out[:, 1], m_out[:, 0]

@@ -32,11 +32,14 @@ after the swap sees the new bank, and a same-shape swap never recompiles
 from __future__ import annotations
 
 import dataclasses
+import math
+import time
 from typing import List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.kernel_bank import KernelBank
 from repro.core.meb import Ball, fold_banks, fold_kernel_banks
@@ -51,6 +54,11 @@ class ScoreRequest:
     request's rows: an (n, B) f32 array for the "scores" epilogue, an
     ``((n, G) int32 class ids, (n, G) f32 margins)`` pair for "ovr", and an
     ``((n, k) f32, (n, k) int32)`` pair for "topk".
+
+    ``t_submit``, ``t_first`` and ``t_done`` are ``time.perf_counter()``
+    stamps, NaN until set: when ``submit`` took the request, when a step
+    packed its first row, and when the answers to its last row reached the
+    host. A zero-row request gets all three at submit.
     """
 
     rid: int
@@ -58,6 +66,9 @@ class ScoreRequest:
     result: Union[np.ndarray, Tuple[np.ndarray, ...], None] = None
     rows_scored: int = 0
     done: bool = False
+    t_submit: float = math.nan
+    t_first: float = math.nan
+    t_done: float = math.nan
 
 
 @dataclasses.dataclass
@@ -99,6 +110,25 @@ class BankServer:
     kernel — in any residency. Kernel banks ignore ``b_tile`` and
     ``bank_resident`` (their state is bounded by construction — the Gram
     operand streams through the tiled kernel's own block pipeline).
+
+    Tracing: under a profiler capture (``jax.profiler.start_trace``) each
+    ``step`` shows as five consecutive host spans, on the device ops' clock:
+    ``serve.pack`` (a fresh query buffer, the FIFO packing loop),
+    ``serve.copy_in`` (``jnp.asarray`` of the buffer; it can return before
+    the transfer ends), ``serve.launch`` (the scoring call, which returns
+    before the device finishes), ``serve.readback`` (``np.asarray`` of the
+    outputs: waits for the transfer in, the kernel and the copy out) and
+    ``serve.scatter`` (answers into requests, the queue rebuild,
+    ``ServerStats``). With no capture running a span costs well under a
+    microsecond. Each ``ScoreRequest`` carries ``time.perf_counter()``
+    stamps: ``t_submit`` (``submit`` took it), ``t_first`` (the step that
+    packed its first row began) and ``t_done`` (the step that answered its
+    last row had its outputs on the host). ``t_first - t_submit`` is the
+    wait in this server's queue and ``t_done - t_first`` the service time;
+    their sum is the server's share of a request's latency. A caller's own
+    wait before ``submit`` (e.g. a single-threaded client busy in ``step``
+    when the request fell due) is not in them: time that from the caller's
+    clock.
     """
 
     def __init__(
@@ -376,6 +406,7 @@ class BankServer:
 
     def submit(self, queries) -> ScoreRequest:
         """Queue a ragged block of query rows; returns its ScoreRequest."""
+        t_submit = time.perf_counter()
         q = np.asarray(queries, np.float32)
         if q.ndim != 2 or q.shape[1] != self._d:
             raise ValueError(
@@ -394,11 +425,14 @@ class BankServer:
                 np.empty((n, self.k), np.float32),
                 np.empty((n, self.k), np.int32),
             )
-        req = ScoreRequest(rid=self._next_rid, queries=q, result=result)
+        req = ScoreRequest(
+            rid=self._next_rid, queries=q, result=result, t_submit=t_submit
+        )
         self._next_rid += 1
         self.stats.admitted += 1
         if n == 0:  # nothing to score — finished on arrival
             req.done = True
+            req.t_first = req.t_done = t_submit
             self.stats.finished += 1
         else:
             self._queue.append(req)
@@ -412,63 +446,74 @@ class BankServer:
         scatter results back. Returns the number of rows scored."""
         if not self._queue:
             return 0
-        buf = np.zeros((self.q_block, self._d), np.float32)
-        segments: List[Tuple[ScoreRequest, int, int, int]] = []
-        filled = 0
-        qi = 0
-        while qi < len(self._queue) and filled < self.q_block:
-            req = self._queue[qi]
-            off = req.rows_scored
-            take = min(req.queries.shape[0] - off, self.q_block - filled)
-            buf[filled : filled + take] = req.queries[off : off + take]
-            segments.append((req, off, take, filled))
-            filled += take
-            qi += 1
-        if self._w is None:
-            out = predict_kernel_bank(
-                jnp.asarray(buf),
-                self._points,
-                self._coef,
-                kernel=self.kernel,
-                gamma=self.gamma,
-                epilogue=self.epilogue,
-                n_classes=self.n_classes,
-                k=self.k,
-                q_block=self.q_block,
-                stream_dtype=self.stream_dtype,
-                interpret=self.interpret,
-            )
-        else:
-            out = predict_bank(
-                jnp.asarray(buf),
-                self._w,
-                epilogue=self.epilogue,
-                n_classes=self.n_classes,
-                k=self.k,
-                q_block=self.q_block,
-                b_tile=self.b_tile,
-                stream_dtype=self.stream_dtype,
-                bank_resident=self.bank_resident,
-                interpret=self.interpret,
-            )
-        parts = (out,) if self.epilogue == "scores" else out
-        parts = tuple(np.asarray(p) for p in parts)
-        finished = 0
-        for req, off, take, at in segments:
-            dests = (
-                (req.result,) if self.epilogue == "scores" else req.result
-            )
-            for dst, src in zip(dests, parts):
-                dst[off : off + take] = src[at : at + take]
-            req.rows_scored = off + take
-            if req.rows_scored == req.queries.shape[0]:
-                req.done = True
-                finished += 1
-        self._queue = [r for r in self._queue if not r.done]
-        self.stats.steps += 1
-        self.stats.slot_busy_rows += filled
-        self.stats.slot_idle_rows += self.q_block - filled
-        self.stats.finished += finished
+        with TraceAnnotation("serve.pack"):
+            t_first = time.perf_counter()
+            buf = np.zeros((self.q_block, self._d), np.float32)
+            segments: List[Tuple[ScoreRequest, int, int, int]] = []
+            filled = 0
+            qi = 0
+            while qi < len(self._queue) and filled < self.q_block:
+                req = self._queue[qi]
+                off = req.rows_scored
+                take = min(req.queries.shape[0] - off, self.q_block - filled)
+                buf[filled : filled + take] = req.queries[off : off + take]
+                if off == 0:
+                    req.t_first = t_first
+                segments.append((req, off, take, filled))
+                filled += take
+                qi += 1
+        with TraceAnnotation("serve.copy_in"):
+            x = jnp.asarray(buf)
+        with TraceAnnotation("serve.launch"):
+            if self._w is None:
+                out = predict_kernel_bank(
+                    x,
+                    self._points,
+                    self._coef,
+                    kernel=self.kernel,
+                    gamma=self.gamma,
+                    epilogue=self.epilogue,
+                    n_classes=self.n_classes,
+                    k=self.k,
+                    q_block=self.q_block,
+                    stream_dtype=self.stream_dtype,
+                    interpret=self.interpret,
+                )
+            else:
+                out = predict_bank(
+                    x,
+                    self._w,
+                    epilogue=self.epilogue,
+                    n_classes=self.n_classes,
+                    k=self.k,
+                    q_block=self.q_block,
+                    b_tile=self.b_tile,
+                    stream_dtype=self.stream_dtype,
+                    bank_resident=self.bank_resident,
+                    interpret=self.interpret,
+                )
+        with TraceAnnotation("serve.readback"):
+            parts = (out,) if self.epilogue == "scores" else out
+            parts = tuple(np.asarray(p) for p in parts)
+        with TraceAnnotation("serve.scatter"):
+            t_done = time.perf_counter()
+            finished = 0
+            for req, off, take, at in segments:
+                dests = (
+                    (req.result,) if self.epilogue == "scores" else req.result
+                )
+                for dst, src in zip(dests, parts):
+                    dst[off : off + take] = src[at : at + take]
+                req.rows_scored = off + take
+                if req.rows_scored == req.queries.shape[0]:
+                    req.done = True
+                    req.t_done = t_done
+                    finished += 1
+            self._queue = [r for r in self._queue if not r.done]
+            self.stats.steps += 1
+            self.stats.slot_busy_rows += filled
+            self.stats.slot_idle_rows += self.q_block - filled
+            self.stats.finished += finished
         return filled
 
     def run(self, max_steps: int = 100_000) -> ServerStats:

@@ -250,3 +250,87 @@ def test_from_checkpoint_rejects_non_bank_trees(tmp_path):
     ckpt.save(path, {"a": jnp.zeros((3,)), "b": jnp.ones((2, 2))})
     with pytest.raises(ValueError, match="4-leaf"):
         BankServer.from_checkpoint(path)
+
+
+# ---------------------------------------------------------------------------
+# Request stamps and the step's phase spans
+# ---------------------------------------------------------------------------
+
+PHASES = ("serve.pack", "serve.copy_in", "serve.launch", "serve.readback",
+          "serve.scatter")
+
+
+def test_request_stamps_are_ordered():
+    rng = np.random.default_rng(10)
+    server = BankServer(rng.normal(size=(8, 8)).astype(np.float32), q_block=8)
+    reqs = [server.submit(rng.normal(size=(n, 8)).astype(np.float32))
+            for n in (5, 11, 2, 8)]
+    assert all(np.isnan(r.t_first) and np.isnan(r.t_done) for r in reqs)
+    server.run()
+    for r in reqs:
+        assert r.t_submit <= r.t_first <= r.t_done
+    assert [r.t_submit for r in reqs] == sorted(r.t_submit for r in reqs)
+
+
+def test_a_request_over_several_steps_is_stamped_by_its_first_and_last():
+    """t_first comes from the step that packs the first row, t_done from
+    the one that scatters the last; requests packed or finished in one step
+    share its stamp."""
+    rng = np.random.default_rng(11)
+    server = BankServer(rng.normal(size=(8, 8)).astype(np.float32), q_block=8)
+    big = server.submit(rng.normal(size=(20, 8)).astype(np.float32))
+    small = server.submit(rng.normal(size=(3, 8)).astype(np.float32))
+    server.step()  # big's rows 0-7
+    first = big.t_first
+    assert np.isfinite(first) and np.isnan(big.t_done)
+    assert np.isnan(small.t_first)
+    server.step()  # big's rows 8-15
+    assert big.t_first == first and np.isnan(big.t_done)
+    server.step()  # big's rows 16-19 and all of small
+    assert big.done and small.done
+    assert big.t_first == first < small.t_first
+    assert big.t_done == small.t_done > small.t_first
+
+
+def test_a_zero_row_request_is_stamped_at_submit():
+    server = BankServer(np.eye(4, dtype=np.float32), q_block=8)
+    req = server.submit(np.zeros((0, 4), np.float32))
+    assert np.isfinite(req.t_submit)
+    assert req.t_submit == req.t_first == req.t_done
+
+
+def test_each_step_emits_the_five_phase_spans_in_order(tmp_path):
+    """Under a profiler capture every step records the five phase spans
+    once each, in order, each starting where the last one ended."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    rng = np.random.default_rng(12)
+    server = BankServer(rng.normal(size=(8, 8)).astype(np.float32), q_block=8)
+    server.score(rng.normal(size=(8, 8)).astype(np.float32))  # compile
+    for n in (20, 3):
+        server.submit(rng.normal(size=(n, 8)).astype(np.float32))
+    steps = server.stats.steps
+    jax.profiler.start_trace(str(tmp_path))
+    server.run()
+    jax.profiler.stop_trace()
+    steps = server.stats.steps - steps
+    assert steps == 3
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1]
+    spans = sorted(
+        (e.start_ns, e.start_ns + e.duration_ns, e.name)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("serve.")
+    )
+    assert [name for _, _, name in spans] == list(PHASES) * steps
+    for i in range(steps):
+        step = spans[i * len(PHASES):(i + 1) * len(PHASES)]
+        gaps = [b[0] - a[1] for a, b in zip(step, step[1:])]
+        assert min(gaps) >= 0
+        # the spans cover the step: what lies between them is the
+        # annotations' own exit and entry, a few microseconds
+        assert sum(gaps) < 0.1 * (step[-1][1] - step[0][0])
