@@ -60,6 +60,7 @@ from .gram import gram_pallas
 from .predict import NEG_MASK, predict_bank_pallas
 from .streamsvm_scan import (
     D_CHUNK,
+    _rows_per_step,
     streamsvm_scan_many_pallas,
     streamsvm_scan_pallas,
 )
@@ -192,7 +193,9 @@ def _stream_bytes(stream_dtype) -> int:
 #: bank (B = 600, D = 784, N = 65,536) at b_tile 256, 512 and 600 and the
 #: beyond-VMEM bank (B = 3000, D = 4096, N = 16,384) at b_tile 64 and 128:
 #: with it the model is at or above the compiler wherever it is near the
-#: budget, so the tile it derives compiles.
+#: budget, so the tile it derives compiles. Algorithm 1's read-ahead loop
+#: was checked at the same points: with its Gram band counted apart
+#: (``gram_band``) the count still holds.
 ROW_LOOP_COLUMNS = 35
 
 
@@ -213,12 +216,13 @@ def engine_vmem_bytes(
     the double-buffered stream and sign tiles (plus the signs' f32 values),
     the bf16 parts Mosaic splits a full-f32 dot's operands into (one
     ``D_CHUNK``-wide chunk of the stream tile and of the bank tile at a
-    time), the block Gram scratch, the per-model parameter tile, the row
-    loop's per-model columns, and the VMEM slots of the bank, its state
-    slabs and the lookahead windows — one slot per bank tile when
-    VMEM-resident, two when the tiles ring through from HBM. The "auto"
-    policy and the preflight ValueError both read this; the BENCH harnesses
-    record its total per row as ``vmem_working_set_bytes``.
+    time), the block Gram scratch and Algorithm 1's band of it, the
+    per-model parameter tile, the row loop's per-model columns, and the
+    VMEM slots of the bank, its state slabs and the lookahead windows — one
+    slot per bank tile when VMEM-resident, two when the tiles ring through
+    from HBM. The "auto" policy and the preflight ValueError both read
+    this; the BENCH harnesses record its total per row as
+    ``vmem_working_set_bytes``.
     """
     sz = _stream_bytes(stream_dtype)
     bt, n_tiles = bank_tiling(b, b_tile)
@@ -227,12 +231,14 @@ def engine_vmem_bytes(
     slots = n_tiles if bank_resident == "vmem" else min(2, n_tiles)
     lane_row = 128 * 4  # one per-model row of a (rows, 128) f32/i32 slab
     f32_copy = 4 if sz != 4 else 0  # bf16 tiles are upcast to f32 values
+    u = 0 if L else _rows_per_step(block_n)  # Algorithm 1's band
     return {
         "stream_tile": 2 * block_n * dp * sz,
         "sign_tile": 2 * bt * block_n * sz + bt * block_n * f32_copy,
         # three bf16 parts (6 bytes) per element of both dot operands
         "dot_split": 6 * (block_n + bt) * min(dp, D_CHUNK),
         "gram": block_n * block_n * 4,
+        "gram_band": 2 * u * block_n * lane_row,
         "params": 2 * bt * lane_row,
         "row_loop": ROW_LOOP_COLUMNS * bt * lane_row,
         "bank": slots * bt * dp * 4,

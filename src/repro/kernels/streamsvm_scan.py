@@ -11,7 +11,9 @@ stream; each grid step:
   3. runs the inherently-sequential conditional updates with an in-register
      fori_loop over the block's rows, maintaining <w, yx_k> for k > j with
      rank-1 corrections from G (O(block_n) per row) and updating w itself
-     with a single AXPY per *accepted* row.
+     with a single AXPY per *accepted* row. The bank engine's loop (below)
+     carries the next rows' g_k forward, so the chain from one row to the
+     next holds no cross-lane sum.
 
 Per-block cost: one (block_n x D x block_n) matmul + block_n * O(block_n + D)
 vector work — MXU-friendly, and exactly equal in result to the reference
@@ -39,6 +41,19 @@ ref, a stream row a sublane read of the stream tile's ref, and the per-model
 columns g[:, j] / y[:, j] are one-hot lane sums (exact in f32). Per-model
 scalars live in (rows, 128) slabs, one model per sublane row, so a bank tile
 of any multiple of 8 models is one aligned slab in VMEM and one aligned DMA.
+
+A lane sum is a round trip through the cross-lane unit, about 100 cycles
+on a v5e, against a chain of about 50 per row (d^2, an exact sqrt and
+divide, s). So the bank's Algorithm-1 loop keeps those sums off the chain:
+it runs ``_rows_per_step`` rows a step, lane-sums the NEXT step's columns
+of g and y at the start of a step, and brings them up to date row by row
+in (b_tile, 128) arithmetic with the very operations the full-width update
+of g applies to that column — so every value, and the result, is the same
+bit for bit. The Gram entries those updates need, G[j, j + t] for t below
+two steps, are laid out once a block as a lane-replicated band
+(``band_ref``), read by sublane. The per-model columns are lane-replicated
+(b_tile, 128) values, so no value needs a lane shift or a broadcast inside
+the loop.
 
 The fused Algorithm-2 variant (``lookahead`` is not None) defers acceptance:
 violating rows are pushed into a per-model L-row window (slot-major
@@ -84,6 +99,7 @@ tile) no matter how large B*D grows.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +134,17 @@ def _dot_nt(a_at, b_at, d: int):
         )
         acc = p if acc is None else acc + p
     return acc
+
+
+def _rows_per_step(block_n: int) -> int:
+    """Rows of a block the Algorithm-1 loop runs per step: two (one for an
+    odd block_n). A step reads the next step's g columns by cross-lane sums
+    at its start (module docstring), so their round trip overlaps the
+    step's own rows. Each further row a step carries two more columns per
+    model, which spill: on a v5e four rows a step ran slower than two both
+    for a one-tile bank of 24 models and for tiles of 256 (PERF.md).
+    """
+    return math.gcd(2, block_n)
 
 
 def _kernel(
@@ -295,9 +322,10 @@ def _bank_flush(w_ref, r, xi2, g, cnt, buf, fmask, x_ref, ys, c_inv, gain):
 
 def _block_update(
     x_ref,  # (block_n, D) stream tile ref (f32 or bf16)
-    ys,  # (b_tile, block_n) f32 per-model label signs
+    ys_ref,  # (b_tile, block_n) per-model label-sign tile (f32 or bf16)
     w_ref,  # (b_tile, D) f32 ref view: the resident bank tile, updated here
     gram_ref,  # (block_n, block_n) f32 VMEM scratch for the block Gram
+    band_ref,  # (2 * _rows_per_step(block_n), block_n, 128) f32 (or None)
     r, xi2, wsq,  # (b_tile, 1) f32 per-model scalars
     m,  # (b_tile, 1) int32 core-vector counts
     cnt,  # (b_tile, 1) int32 lookahead fill counts (None for Algorithm 1)
@@ -318,7 +346,8 @@ def _block_update(
     Both residencies run it on a bank tile staged in a VMEM slot, which is
     what makes them bit-exact in f32: only how long the tile stays in its
     slot differs, never the arithmetic applied to it. Per-model scalars
-    are (b_tile, 1) columns, one model per sublane row like the bank tile.
+    come in as (b_tile, 1) columns, one model per sublane row like the bank
+    tile (Algorithm 1 widens them to lane-replicated (b_tile, 128)).
     The row loop reads row jr without dynamic value slicing (which Mosaic
     does not lower): the Gram row is a sublane read of ``gram_ref``, stream
     row jr a sublane read of ``x_ref``, and the (b_tile,) columns ``g[:, jr]``
@@ -327,7 +356,22 @@ def _block_update(
     ``w_ref`` and returns ``(r, xi2, wsq, m, cnt)`` (cnt None for
     Algorithm 1). The stream tile and the bank tile are re-read from their
     refs at each use rather than held as values across the row loop, which
-    would make Mosaic keep a (block_n, D) and a (b_tile, D) copy in VMEM.
+    would make Mosaic keep a (block_n, D) and a (b_tile, D) copy in VMEM;
+    so are the signs, from ``ys_ref``.
+
+    Algorithm 1's loop runs ``_rows_per_step`` rows a step and carries each
+    row's g column and signs into the step that uses it: at the start
+    of a step it lane-sums the next step's columns out of the g the step
+    starts with, and each row of the step corrects them with its own update,
+    ``gk = one_s * gk + (s * y_j) * (y_k * G[j, k])``: the operations, in
+    order, that the full-width ``g`` update applies to column k. G[j, k]
+    and G_jj are sublane reads of ``band_ref`` (t, k) = G[k, k + t], filled
+    once a block from ``gram_ref``. The chain from a row to the next (d^2,
+    sqrt, divide, s, that correction) thus holds no lane sum; the
+    full-width update of g, which only feeds the column sums a step later,
+    and that of alpha leave it. On a block's last step the columns read
+    ahead lie past the block: their one-hot masks are all false and the band
+    reads are clamped to the block, and no row uses them.
     """
     d = x_ref.shape[1]
     x_at = lambda c: x_ref[:, c].astype(jnp.float32)  # bf16 tiles upcast here
@@ -336,6 +380,113 @@ def _block_update(
     # products — the only O(D) work in the block, all MXU.
     gram_ref[...] = _dot_nt(x_at, x_at, d)  # (block_n, block_n)
     h0 = _dot_nt(lambda c: w_ref[:, c], x_at, d)  # (b_tile, block_n): <w_b, x_k>
+
+    if lookahead_max is None:
+        # ----- Algorithm 1: immediate greedy acceptance (bit-exact with the
+        # single-tile path — identical per-row arithmetic). -----
+        u = _rows_per_step(block_n)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+        reps = -(-block_n // STATE_LANES)
+
+        def rep(v):  # (rows, 1) -> (rows, 128): the column in every lane
+            return jnp.broadcast_to(v, (v.shape[0], STATE_LANES))
+
+        def wide(v):  # (rows, 128) lane-replicated -> (rows, block_n)
+            v = jnp.concatenate([v] * reps, axis=1) if reps > 1 else v
+            return v[:, :block_n]
+
+        def lane_sum(mask, v):  # exact: every other term is 0.0
+            return rep(jnp.sum(jnp.where(mask, v, 0.0), axis=1, keepdims=True))
+
+        ys_at = lambda: ys_ref[...].astype(jnp.float32)
+        # The Gram's band, lane-replicated: band_ref[t, k] = G[k, k + t]
+        # (0.0 past the block), so a row reads its entries by sublane.
+        g_rows = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 0)
+        g_cols = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
+        for t in range(band_ref.shape[0]):
+            band_ref[t] = lane_sum(g_cols == g_rows + t, gram_ref[...])
+        band = lambda t, k: band_ref[
+            t, pl.ds(jnp.minimum(k, block_n - 1), 1), :
+        ]
+
+        def read_ahead(g, k):
+            """Row k's g column and signs (unused for k >= block_n)."""
+            hit = lanes == k
+            return lane_sum(hit, g), lane_sum(hit, ys_at())
+
+        def body(i, carry):
+            g, alpha, decay, r, xi2, wsq, m, cols = carry
+            j0 = i * u
+            cols = list(cols)
+            # The next step's columns, read before this step's rows update g:
+            # each row below brings them up to date in (b_tile, 128) columns.
+            ahead = [read_ahead(g, j0 + u + t) for t in range(u)]
+            ys = ys_at()
+            for a in range(u):
+                jr = j0 + a
+                grow = gram_ref[pl.ds(jr, 1), :]  # (1, block_n)
+                gj, yj = cols[a]
+                gjj = band(0, jr)  # G[j, j]
+                # Inert per model: rows past n_valid, and rows whose sign is
+                # 0 for that model (fit_bank_sharded's stream padding; padded
+                # bank rows).
+                live = jnp.logical_and(yj != 0.0, row0 + jr < n_valid)
+                d2 = wsq - 2.0 * gj + gjj + xi2 + c_inv
+                d = jnp.sqrt(jnp.maximum(d2, 1e-12))
+                upd = jnp.logical_and(d >= r, live)
+                s = jnp.where(upd, 0.5 * (1.0 - r / d), 0.0)
+                one_s = 1.0 - s
+                sy = s * yj
+
+                def correct(col, k):
+                    """This row's update of column k of g, as the full-width
+                    update below computes it."""
+                    gk, yk = col
+                    g_jk = band(k - jr, jr)  # G[j, k]
+                    return one_s * gk + sy * (yk * g_jk), yk
+
+                cols[a + 1:] = [
+                    correct(c, jr + 1 + t) for t, c in enumerate(cols[a + 1:])
+                ]
+                ahead = [correct(c, j0 + u + t) for t, c in enumerate(ahead)]
+                # rank-1 maintenance of g under w_b <- (1-s_b) w_b + s_b y_bj
+                # x_j: <x_j, y_bk x_k> = y_bk G[j, k]
+                g = wide(one_s) * g + wide(sy) * (ys * grow)
+                # Deferred bank update: w_end = decay * w_start + sum_j
+                # alpha_j y_bj x_j with alpha_j = s_j * prod_{k>j} (1 - s_k)
+                # — applied post-loop as ONE (b_tile, block_n) x (block_n, D)
+                # matmul.
+                alpha = wide(one_s) * alpha + jnp.where(
+                    lanes == jr, wide(s), 0.0
+                )
+                decay = decay * one_s
+                wsq = one_s**2 * wsq + 2.0 * s * one_s * gj + s**2 * gjj
+                r = jnp.where(upd, r + 0.5 * (d - r), r)
+                xi2 = xi2 * one_s**2 + s**2 * gain
+                m = m + upd.astype(jnp.int32)
+            return g, alpha, decay, r, xi2, wsq, m, tuple(ahead)
+
+        g0 = ys_at() * h0  # g[b, k] = <w_b, y_bk x_k>
+        c_inv, gain = rep(c_inv), rep(gain)
+        init = (
+            g0, jnp.zeros_like(g0), jnp.ones((b_tile, STATE_LANES), jnp.float32),
+            rep(r), rep(xi2), rep(wsq), rep(m),
+            tuple(read_ahead(g0, t) for t in range(u)),
+        )
+        g, alpha, decay, r, xi2, wsq, m, _ = jax.lax.fori_loop(
+            0, block_n // u, body, init
+        )
+        coef = alpha * ys_at()
+        for c in _d_chunks(d):
+            w_ref[:, c] = decay[:, :1] * w_ref[:, c] + jax.lax.dot_general(
+                coef, x_at(c), (((1,), (0,)), ((), ())),
+                precision=_F32, preferred_element_type=jnp.float32,
+            )
+        return r[:, :1], xi2[:, :1], wsq[:, :1], m[:, :1], None
+
+    # ----- Algorithm 2: deferred acceptance through per-model L-row
+    # lookahead windows, flushed farthest-point-first. -----
+    ys = ys_ref[...].astype(jnp.float32)
     g0 = ys * h0  # g[b, k] = <w_b, y_bk x_k>
     col_ids = jax.lax.broadcasted_iota(jnp.int32, ys.shape, 1)  # (b_tile, block_n)
 
@@ -355,50 +506,6 @@ def _block_update(
         live = jnp.logical_and(yj != 0.0, row0 + jr < n_valid)  # (b_tile, 1)
         return hit, grow, gjj, gj, yj, live
 
-    if lookahead_max is None:
-        # ----- Algorithm 1: immediate greedy acceptance (bit-exact with the
-        # single-tile path — identical per-row arithmetic). -----
-        def body(jr, carry):
-            g, alpha, decay, r, xi2, wsq, m = carry
-            hit, grow, gjj, gj, yj, live = read_row(jr, g)
-            d2 = wsq - 2.0 * gj + gjj + xi2 + c_inv
-            d = jnp.sqrt(jnp.maximum(d2, 1e-12))
-            upd = jnp.logical_and(d >= r, live)
-            s = jnp.where(upd, 0.5 * (1.0 - r / d), 0.0)  # (b_tile, 1)
-            one_s = 1.0 - s
-            # rank-1 maintenance of g under w_b <- (1-s_b) w_b + s_b y_bj x_j:
-            # <x_j, y_bk x_k> = y_bk G[j, k]
-            g = one_s * g + (s * yj) * (ys * grow)
-            # Deferred bank update: w_end = decay * w_start + sum_j alpha_j
-            # y_bj x_j with alpha_j = s_j * prod_{k>j} (1 - s_k) — applied
-            # post-loop as ONE (b_tile, block_n) x (block_n, D) matmul.
-            alpha = one_s * alpha + jnp.where(hit, s, 0.0)
-            decay = decay * one_s
-            wsq = one_s**2 * wsq + 2.0 * s * one_s * gj + s**2 * gjj
-            r = jnp.where(upd, r + 0.5 * (d - r), r)
-            xi2 = xi2 * one_s**2 + s**2 * gain
-            m = m + upd.astype(jnp.int32)
-            return g, alpha, decay, r, xi2, wsq, m
-
-        init = (
-            g0,
-            jnp.zeros_like(g0),
-            jnp.ones((b_tile, 1), jnp.float32),
-            r, xi2, wsq, m,
-        )
-        g, alpha, decay, r, xi2, wsq, m = jax.lax.fori_loop(
-            0, block_n, body, init
-        )
-        coef = alpha * ys
-        for c in _d_chunks(d):
-            w_ref[:, c] = decay * w_ref[:, c] + jax.lax.dot_general(
-                coef, x_at(c), (((1,), (0,)), ((), ())),
-                precision=_F32, preferred_element_type=jnp.float32,
-            )
-        return r, xi2, wsq, m, None
-
-    # ----- Algorithm 2: deferred acceptance through per-model L-row
-    # lookahead windows, flushed farthest-point-first. -----
     slot = jax.lax.broadcasted_iota(jnp.int32, (lookahead_max, b_tile, 1), 0)
 
     def flush(fmask, g, r, xi2, wsq, cnt):
@@ -472,8 +579,9 @@ def _kernel_many(
     lookahead cnt (B, 128) i32 + buf (L_max, B, D) f32], then ``n_arrays``
     VMEM slot buffers with a leading ``n_slots`` axis, then one
     DMA-semaphore array of shape (n_arrays, 2, 2) = (array, in/out, slot),
-    then the (block_n, block_n) Gram scratch. Every tile is a sublane slab
-    (rows tile*b_tile ...), so each DMA is 8-aligned.
+    then the (block_n, block_n) Gram scratch and, for Algorithm 1, the
+    (2 * _rows_per_step(block_n), block_n, 128) Gram band. Every tile is a sublane
+    slab (rows tile*b_tile ...), so each DMA is 8-aligned.
 
     ``n_slots == n_btiles`` is the VMEM-resident layout: every tile owns a
     slot, loads on the first data block and writes back after the last.
@@ -487,6 +595,7 @@ def _kernel_many(
     slots = refs[2 * n_arrays : 3 * n_arrays]
     sems = refs[3 * n_arrays]
     gram_ref = refs[3 * n_arrays + 1]
+    band_ref = refs[3 * n_arrays + 2] if lookahead_max is None else None
 
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -557,7 +666,7 @@ def _kernel_many(
     params = p_ref[...]
     lookahead = lookahead_max is not None
     r, xi2, wsq, m, cnt = _block_update(
-        x_ref, ys_ref[...].astype(jnp.float32), bank.at[slot], gram_ref,
+        x_ref, ys_ref, bank.at[slot], gram_ref, band_ref,
         st[:, 0:1], st[:, 1:2], st[:, 2:3], m_slots[slot][:, 0:1],
         slots[3][slot][:, 0:1] if lookahead else None,
         slots[4].at[slot] if lookahead else None,
@@ -784,7 +893,12 @@ def streamsvm_scan_many_pallas(
         scratch_shapes=slot_bufs + [
             pltpu.SemaphoreType.DMA((n_arrays, 2, 2)),
             pltpu.VMEM((block_n, block_n), jnp.float32),
-        ],
+        ] + ([] if lookahead_max is not None else [
+            pltpu.VMEM(
+                (2 * _rows_per_step(block_n), block_n, STATE_LANES),
+                jnp.float32,
+            ),
+        ]),
         input_output_aliases={4 + a: a for a in range(n_arrays)},
         interpret=interpret,
         name="streamsvm_scan_many",

@@ -96,6 +96,80 @@ def test_row_reads_match_ref_with_inert_column(b, n, d, block_n, b_tile):
     np.testing.assert_array_equal(np.asarray(vmem.m), np.asarray(m))
 
 
+def _edge_stream(kind, block_n, seed):
+    """(X, Y, cs, accepted): a stream shaped to stress the row loop's
+    look-ahead, and stream rows that every model must accept.
+
+    Stream row k + 1 is row k of the kernel's blocks (row 0 seeds the ball).
+    """
+    rng = np.random.default_rng(seed)
+    b, d, n = 5, 12, 2 * block_n + 40
+    accepted = []
+    if kind == "every_row_violates":
+        # Nearly orthogonal rows whose norms grow 15% a row, and C large
+        # enough that the slack never encloses the next one.
+        n = d = 130
+        base = 3.0 * np.eye(n) + 0.2 * rng.normal(size=(n, n))
+        X = base * 1.15 ** (np.arange(n) - n + 1)[:, None]
+        cs = np.exp(rng.uniform(9, 14, size=b))
+    else:
+        X = rng.normal(size=(n, d))
+        cs = np.exp(rng.uniform(-1, 4, size=b))
+    if kind == "violators_across_block_edge":
+        # Far outliers at the first block's last row and the next block's
+        # first row: two acceptances in a row across the block boundary.
+        X[block_n : block_n + 2] *= 100.0
+        accepted = [block_n, block_n + 1]
+    if kind == "n_valid_mid_block":
+        n = block_n + block_n // 2 + 1  # the last block is half padding
+        X = X[:n]
+    Y = np.sign(rng.normal(size=(b, n)))
+    if kind == "sign0_next_to_live":
+        Y[:, 2::5] = 0.0  # rows inert for every model, between live rows
+        Y[rng.random(size=(b, n)) < 0.3] = 0.0  # and inert for single models
+        Y[:, 0] = 1.0  # row 0 seeds every model
+    return (
+        jnp.asarray(X.astype(np.float32)), jnp.asarray(Y.astype(np.float32)),
+        jnp.asarray(cs.astype(np.float32)), accepted,
+    )
+
+
+@pytest.mark.parametrize("block_n", [8, 128, 256])
+@pytest.mark.parametrize("kind", [
+    "every_row_violates", "violators_across_block_edge", "n_valid_mid_block",
+    "sign0_next_to_live",
+])
+def test_row_loop_lookahead_matches_ref(kind, block_n):
+    """The row loop reads its rows' g columns a step ahead and corrects them
+    by each row's update. Against the plain-jnp scan: acceptances on
+    consecutive rows, across a block boundary, a last block that ends mid
+    way, and sign-0 rows next to live ones."""
+    X, Y, cs, accepted = _edge_stream(kind, block_n, seed=block_n)
+    bank = streamsvm_fit_many(X, Y, cs, block_n=block_n)
+    c_inv = 1.0 / cs
+    W0 = Y[:, 0:1] * X[0][None, :]
+
+    def ref(k):  # the reference after stream rows [0, k)
+        return streamsvm_scan_many_ref(
+            X[1:k], Y[:, 1:k], W0, 0.0, c_inv, c_inv, 1, gain=c_inv
+        )
+
+    w, r, xi2, m = ref(X.shape[0])
+    # the stream is what its name says
+    if kind == "every_row_violates":
+        np.testing.assert_array_equal(np.asarray(m), X.shape[0])
+    for k in accepted:
+        np.testing.assert_array_equal(
+            np.asarray(ref(k + 1)[3]) - np.asarray(ref(k)[3]), 1
+        )
+    np.testing.assert_allclose(np.asarray(bank.w), np.asarray(w), rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(bank.r), np.asarray(r), rtol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(bank.xi2), np.asarray(xi2), rtol=1e-3, atol=1e-6
+    )
+    np.testing.assert_array_equal(np.asarray(bank.m), np.asarray(m))
+
+
 def test_padded_model_rows_stay_inert():
     """B % b_tile != 0 pads model lanes; results must equal the unpadded run
     and contain no NaN/inf leakage from the padded lanes."""
