@@ -14,8 +14,8 @@ Two entry points:
                       pass each, folded with the bank-vectorized merge
                       (meb.fold_merge over the gathered (S, B, ...) stack,
                       outside the mesh program when called eagerly).
-                      Ragged streams are padded with inert sign-0 rows, so
-                      any N works on any shard count.
+                      Each shard reads its own live row count, so any N
+                      works on any shard count.
 ``fit_kernel_bank_sharded``
                       the KERNELIZED bank per shard (bounded core-set
                       buffers), folded with the kernelized Sec-4.3 merge
@@ -140,18 +140,20 @@ def fit_sharded(
 @partial(
     jax.jit,
     static_argnames=(
-        "mesh", "axes", "variant", "lookahead", "block_n", "b_tile",
-        "stream_dtype", "bank_resident", "interpret",
+        "mesh", "axes", "n_rows", "variant", "lookahead", "block_n",
+        "b_tile", "stream_dtype", "bank_resident", "interpret",
     ),
 )
 def _sharded_fits(
     X, Y, cs, *,
-    mesh, axes, variant, lookahead, block_n, b_tile, stream_dtype,
+    mesh, axes, n_rows, variant, lookahead, block_n, b_tile, stream_dtype,
     bank_resident, interpret,
 ):
     """jit'd shard_map core of fit_bank_sharded: one bank fit per shard,
     gathered into an (S, B, ...) stack replicated on every device, NO
-    fold.
+    fold. Shard k holds rows ``k * shard_n ..`` of the stream, whose first
+    ``n_rows`` are live: each shard's fit reads its own live row count, so
+    rows past the stream are inert whatever they hold.
 
     Module-level so repeated calls with the same (shapes, mesh, config) hit
     the jit cache instead of rebuilding and re-tracing the shard_map closure
@@ -161,11 +163,16 @@ def _sharded_fits(
     def local_fit(Xs, Ys, cs_):
         from repro.kernels.ops import streamsvm_fit_many  # lazy: module cycle
 
+        shard_n = Xs.shape[0]
+        sid = jnp.zeros((), jnp.int32)
+        for a in axes:
+            sid = sid * mesh.shape[a] + jax.lax.axis_index(a)
         bank = streamsvm_fit_many(
             Xs, Ys, cs_, None,
             variant=variant, lookahead=lookahead, block_n=block_n,
             b_tile=b_tile, stream_dtype=stream_dtype,
             bank_resident=bank_resident, interpret=interpret,
+            n_valid=jnp.clip(n_rows - sid * shard_n, 0, shard_n),
         )
         return jax.tree.map(
             lambda v: jax.lax.all_gather(v, axes, tiled=False), bank
@@ -434,6 +441,29 @@ def fit_kernel_bank_shards(
     )
 
 
+def _place_rows(A, sharding: NamedSharding, axis: int, size: int) -> jax.Array:
+    """``A`` split along ``axis`` as ``sharding`` lays out ``size`` (>= A's)
+    entries, each device receiving only its own slice; entries past A's end
+    are zeros on the device that holds them. So no copy of the whole of A is
+    made; an A already laid out so is returned as it is."""
+    n = A.shape[axis]
+    if n == size:
+        return jax.device_put(A, sharding)
+    shape = A.shape[:axis] + (size,) + A.shape[axis + 1:]
+    parts = []
+    for dev, index in sharding.addressable_devices_indices_map(shape).items():
+        lo, hi, _ = index[axis].indices(size)
+        take = [slice(None)] * A.ndim
+        take[axis] = slice(min(lo, n), min(hi, n))
+        part = jax.device_put(A[tuple(take)], dev)
+        if part.shape[axis] < hi - lo:
+            widths = [(0, 0)] * A.ndim
+            widths[axis] = (0, hi - lo - part.shape[axis])
+            part = jnp.pad(part, widths)
+        parts.append(part)
+    return jax.make_array_from_single_device_arrays(shape, sharding, parts)
+
+
 def fit_bank_sharded(
     X: jax.Array,
     Y: jax.Array,
@@ -466,13 +496,29 @@ def fit_bank_sharded(
     each stream row is read from HBM exactly once, on exactly one shard.
 
     X: (N, D) stream, Y: (B, N) per-model sign rows, cs: scalar or (B,)
-    per-model C (traced). ``N % n_shards != 0`` is fine: the remainder is
-    padded with inert rows (feature 0, sign 0 — the engine's sign-0 contract
-    guarantees they update nothing), and shards whose whole range is padding
-    are masked out of the fold, so the result is identical to folding the
-    unpadded ragged ranges. (Padding is always a suffix, so every LIVE
-    shard's first row — its engine init example — is a real stream row;
-    the init caveat on ``streamsvm_fit_many`` never triggers here.)
+    per-model C (traced). Shard k holds rows ``[k * shard_n, (k + 1) *
+    shard_n)`` with ``shard_n = ceil(N / n_shards)`` (``shard_ranges``), and
+    its fit reads its own live row count, so rows past the stream are inert
+    whatever they hold. For N divisible by the shard count X and Y pass
+    through untouched: an X already split by rows over the mesh is read in
+    place, with no copy of the stream. ``N % n_shards != 0`` is fine too:
+    called eagerly, each device receives only its own rows (the ragged last
+    shard filled up to ``shard_n`` rows on its own device), never a padded
+    copy of the whole stream; under a trace the stream is padded in the
+    program. Shards with no live row are left out of the fold, so the
+    result is identical to folding the ragged ranges. (Every live shard's
+    first row — its engine init example — is a real stream row, so the init
+    caveat on ``streamsvm_fit_many`` never triggers here.)
+
+    Called eagerly, the fit runs as four phases, each a
+    ``jax.profiler.TraceAnnotation`` span that ends when its result is
+    ready (points where the fit waits anyway):
+
+    - ``fit.shards``: the mesh program, dispatch until its stacked (S, B,
+      ...) banks are ready;
+    - ``fit.gather``: the stacked banks to the host;
+    - ``fit.fold``: ``fold_merge`` of the live shards on one device;
+    - ``fit.place``: the folded bank replicated on the mesh.
 
     ``balls`` (a stacked bank) continues a previous fit: shards fit their
     ranges FRESH (keeping shard example-sets disjoint, which the merge's
@@ -497,25 +543,25 @@ def fit_bank_sharded(
         lookahead = tuple(lookahead)
 
     shard_n = -(-n // n_shards)  # rows per shard, ceil
-    pad = shard_n * n_shards - n
-    if pad:
-        # Inert remainder rows: feature 0 AND sign 0 — the engine never lets
-        # them violate, absorb, or enter a lookahead window, so the padded
-        # run is bit-identical to fitting the ragged ranges directly.
-        X = jnp.pad(X, ((0, pad), (0, 0)))
-        Y = jnp.pad(Y, ((0, 0), (0, pad)))
-    if not isinstance(X, jax.core.Tracer):  # eager call: place shards up front
-        X = jax.device_put(X, NamedSharding(mesh, P(axes)))
-        Y = jax.device_put(Y, NamedSharding(mesh, P(None, axes)))
-    stacked = _sharded_fits(
-        X, Y, cs,
-        mesh=mesh, axes=axes, variant=variant, lookahead=lookahead,
+    if isinstance(X, jax.core.Tracer) or isinstance(Y, jax.core.Tracer):
+        pad = shard_n * n_shards - n
+        if pad:
+            X = jnp.pad(X, ((0, pad), (0, 0)))
+            Y = jnp.pad(Y, ((0, 0), (0, pad)))
+    else:  # eager call: place shards up front, each device its own rows
+        X = _place_rows(X, NamedSharding(mesh, P(axes)), 0, shard_n * n_shards)
+        Y = _place_rows(Y, NamedSharding(mesh, P(None, axes)), 1,
+                        shard_n * n_shards)
+    fits = partial(
+        _sharded_fits,
+        mesh=mesh, axes=axes, n_rows=n, variant=variant, lookahead=lookahead,
         block_n=block_n, b_tile=b_tile, stream_dtype=stream_dtype,
         bank_resident=bank_resident, interpret=interpret,
     )
-    # Shards whose whole range is padding (a suffix) stay out of the fold.
+    # Shards with no live row (a suffix) stay out of the fold.
     n_live = -(-n // shard_n)
-    if isinstance(stacked.w, jax.core.Tracer):
+    if isinstance(X, jax.core.Tracer):
+        stacked = fits(X, Y, cs)
         folded = fold_merge(jax.tree.map(lambda v: v[:n_live], stacked))
     else:
         # Eager call: fold the per-shard banks off the mesh, on the default
@@ -523,13 +569,16 @@ def fit_bank_sharded(
         # folded with; then replicate. Compiled into the mesh program, XLA
         # may fuse the merge arithmetic differently, which on a TPU moves
         # the last bits of the result. The host hop copies bits exactly.
-        one = jax.tree.map(
-            lambda v: jax.device_put(np.asarray(v)[:n_live]), stacked
-        )
-        folded = jax.tree.map(
-            lambda v: jax.device_put(v, NamedSharding(mesh, P())),
-            fold_merge(one),
-        )
+        with jax.profiler.TraceAnnotation("fit.shards"):
+            stacked = jax.block_until_ready(fits(X, Y, cs))
+        with jax.profiler.TraceAnnotation("fit.gather"):
+            host = jax.tree.map(lambda v: np.asarray(v)[:n_live], stacked)
+        with jax.profiler.TraceAnnotation("fit.fold"):
+            folded = jax.block_until_ready(
+                fold_merge(jax.tree.map(jax.device_put, host)))
+        with jax.profiler.TraceAnnotation("fit.place"):
+            folded = jax.block_until_ready(jax.tree.map(
+                lambda v: jax.device_put(v, NamedSharding(mesh, P())), folded))
     if balls is not None:
         # The prior bank saw a disjoint (earlier) slice of the stream, so it
         # merges exactly like one more shard.

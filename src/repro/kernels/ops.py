@@ -1,20 +1,22 @@
 """jit'd public wrappers around the Pallas kernels (padding, dtype policy).
 
 These are the entry points the rest of the framework uses; they handle
-128-alignment padding, interpret-mode selection (``resolve_interpret``: the
-one place it is decided), bank tiling (`b_tile`), the stream dtype policy,
-and state packing. Semantics match ref.py exactly (tests sweep shapes and
-dtypes).
+128-alignment padding (the lanes of a stream whose D is not a multiple of
+128; otherwise the bank engine reads its stream and signs in place),
+interpret-mode selection (``resolve_interpret``: the one place it is
+decided), bank tiling (`b_tile`), the stream dtype policy, and state
+packing. Semantics match ref.py exactly (tests sweep shapes and dtypes).
 
 Dtype policy
 ------------
-``stream_dtype`` controls the precision the *streamed* tiles — the
-(block_n, D) data tiles and (b_tile, block_n) sign tiles — are DMA'd from
-HBM as. ``"bf16"`` halves stream HBM traffic, which is the dominant byte
-term at scale (the bank is O(B*D) once, the stream is O(N*D) every fit).
-The bank, ball scalars, and every in-kernel accumulator stay f32
-regardless. Labels in {-1, 0, +1} are exact in bf16; feature rounding is
-bounded by the bf16 eps sweep in tests/test_tiled_engine.py.
+``stream_dtype`` controls the precision the *streamed* (block_n, D) data
+and (b_tile, block_n) sign tiles are DMA'd from HBM as. ``"bf16"`` halves
+stream HBM traffic, which is the dominant byte term at scale (the bank is
+O(B*D) once, the stream is O(N*D) every fit), at the price of one cast copy
+of X and Y (an f32 stream is read in place, ``reads_in_place``). The bank,
+ball scalars, and every in-kernel accumulator stay f32 regardless. Labels
+in {-1, 0, +1} are exact in bf16; feature rounding is bounded by the bf16
+eps sweep in tests/test_tiled_engine.py.
 
 Compile caching
 ---------------
@@ -185,6 +187,17 @@ def _stream_bytes(stream_dtype) -> int:
     return 2 if dt == jnp.bfloat16 else 4
 
 
+def reads_in_place(d: int, x_dtype, stream_dtype) -> bool:
+    """Whether the bank engine reads the caller's X and Y in place.
+
+    Only an f32 stream that arrives as f32 with D a multiple of 128 is read
+    in place. Any other (lanes to pad, a cast, a bf16 stream) is copied
+    once by ``streamsvm_fit_many``. ``engine_vmem_bytes`` counts the
+    in-place tiles by the same rule."""
+    sdt = jnp.dtype(_resolve_stream_dtype(stream_dtype) or jnp.float32)
+    return d % 128 == 0 and sdt == jnp.float32 and jnp.dtype(x_dtype) == sdt
+
+
 #: (b_tile, 1) per-model columns the engine's row loop keeps live at once
 #: (r, xi2, wsq, m, decay, c_inv, gain and their temporaries). Mosaic holds
 #: each in (8, 128) tiles, so one costs b_tile * 128 * 4 bytes of VMEM. The
@@ -208,21 +221,25 @@ def engine_vmem_bytes(
     stream_dtype=None,
     lookahead_max: int | None = None,
     bank_resident: str = "vmem",
+    x_dtype=jnp.float32,
 ) -> dict:
     """Per-step VMEM working set of the training engine, bytes by term.
 
     Models the padded shapes the kernel allocates (D to the lane multiple
     of 128, B to whole bank tiles, per-model arrays to 128-lane rows):
-    the double-buffered stream and sign tiles (plus the signs' f32 values),
-    the bf16 parts Mosaic splits a full-f32 dot's operands into (one
+    the double-buffered stream and sign tiles (for a stream read in place,
+    with the small tiles that hold the next block's first row and column,
+    and the signs' realigned copy), the bf16 parts Mosaic splits a full-f32 dot's operands into (one
     ``D_CHUNK``-wide chunk of the stream tile and of the bank tile at a
-    time), the block Gram scratch and Algorithm 1's band of it, the
-    per-model parameter tile, the row loop's per-model columns, and the
-    VMEM slots of the bank, its state slabs and the lookahead windows — one
-    slot per bank tile when VMEM-resident, two when the tiles ring through
-    from HBM. The "auto" policy and the preflight ValueError both read
-    this; the BENCH harnesses record its total per row as
-    ``vmem_working_set_bytes``.
+    time) and the realigned stream chunk, the block Gram scratch and
+    Algorithm 1's band of it, the per-model parameter tile, the row loop's
+    per-model columns, and the VMEM slots of the bank, its state slabs and
+    the lookahead windows — one slot per bank tile when VMEM-resident, two
+    when the tiles ring through from HBM. The "auto" policy and the
+    preflight ValueError both read this; the BENCH harnesses record its
+    total per row as ``vmem_working_set_bytes``. ``x_dtype`` is the dtype X
+    arrives in, which with ``reads_in_place`` decides whether the stream is
+    read in place.
     """
     sz = _stream_bytes(stream_dtype)
     bt, n_tiles = bank_tiling(b, b_tile)
@@ -230,13 +247,23 @@ def engine_vmem_bytes(
     L = lookahead_max or 0
     slots = n_tiles if bank_resident == "vmem" else min(2, n_tiles)
     lane_row = 128 * 4  # one per-model row of a (rows, 128) f32/i32 slab
-    f32_copy = 4 if sz != 4 else 0  # bf16 tiles are upcast to f32 values
+    in_place = reads_in_place(d, x_dtype, stream_dtype)  # else X is copied
+    next_rows = 8 if in_place else 0  # the tile of the next block's first row
+    f32_copy = 4 if sz != 4 else 0  # bf16 sign tiles are upcast to f32 values
     u = 0 if L else _rows_per_step(block_n)  # Algorithm 1's band
     return {
-        "stream_tile": 2 * block_n * dp * sz,
-        "sign_tile": 2 * bt * block_n * sz + bt * block_n * f32_copy,
+        "stream_tile": 2 * (block_n + next_rows) * dp * sz,
+        # signs in the stream dtype; read in place, their realigned copy
+        # and the next block's first column too
+        "sign_tile": 2 * bt * block_n * sz + bt * block_n * f32_copy
+        + in_place * (bt * block_n * 4 + 2 * bt * 128 * 4),
         # three bf16 parts (6 bytes) per element of both dot operands
         "dot_split": 6 * (block_n + bt) * min(dp, D_CHUNK),
+        # a stream read in place (f32, D a multiple of 128) realigned by a
+        # row and masked, a D chunk at a time: the rolled and the masked f32
+        # chunk (+0.9 MB on the v5e compiler's scoped VMEM at B 3000, D 4096,
+        # tile 64)
+        "realign": 2 * block_n * min(dp, D_CHUNK) * 4 if in_place else 0,
         "gram": block_n * block_n * 4,
         "gram_band": 2 * u * block_n * lane_row,
         "params": 2 * bt * lane_row,
@@ -422,6 +449,7 @@ def plan_bank_engine(
     lookahead_max: int | None = None,
     bank_resident: str = "auto",
     vmem_budget_bytes: int | None = None,
+    x_dtype=jnp.float32,
 ) -> tuple[str, int | None]:
     """The training engine's ``(residency, b_tile)`` for one configuration.
 
@@ -436,7 +464,7 @@ def plan_bank_engine(
     budget = _vmem_budget(vmem_budget_bytes)
     bytes_at = lambda bt_, res: engine_vmem_bytes(
         b, d, block_n=block_n, b_tile=bt_, stream_dtype=stream_dtype,
-        lookahead_max=lookahead_max, bank_resident=res,
+        lookahead_max=lookahead_max, bank_resident=res, x_dtype=x_dtype,
     )
     if b_tile is None and bank_resident in _BANK_RESIDENCIES:
         order = ("vmem", "hbm") if bank_resident == "auto" else (bank_resident,)
@@ -530,18 +558,34 @@ def streamsvm_fit_many(
     bank_resident: str = "auto",
     vmem_budget_bytes: int | None = None,
     interpret: bool | None = None,
+    n_valid=None,
 ) -> Ball:
     """One-pass Algorithm 1/2 for a bank of B models — ONE read of the stream.
 
     X: (N, D) shared stream; Y: (B, N) per-model label signs in {-1, +1}
     (classes x C-grid x variants all flatten onto the B axis). A sign of 0
     marks a STREAMED row inert *for that model* — no violation, no absorb,
-    no lookahead buffering — which is how core.fit_bank_sharded pads ragged
-    shard remainders without changing any model. Caveat: when ``balls`` is
-    None, row 0 is consumed as every model's init example BEFORE the
-    contract applies, so it must carry a real +-1 sign for every model
-    (``Y[b, 0] == 0`` would seed model b from the zero point w=0, m=1 —
-    pass an explicit ``balls`` or keep sign-0 rows off position 0).
+    no lookahead buffering. ``n_valid`` (traced; default N) counts the live
+    rows of X and Y: rows past it are inert for every model whatever they
+    hold, which is how core.fit_bank_sharded gives each shard its live row
+    count. Caveat: when ``balls`` is None, row 0 is consumed as every
+    model's init example BEFORE the contract applies, so it must carry a
+    real +-1 sign for every model (``Y[b, 0] == 0`` would seed model b from
+    the zero point w=0, m=1 — pass an explicit ``balls`` or keep sign-0
+    rows off position 0).
+
+    X and Y are read in place (``reads_in_place``). The engine makes no
+    copy of either when D is a multiple of 128, the stream is f32 and X and
+    Y are f32, for any N and any B: the seed is taken from ``X[0]`` and
+    ``Y[:, 0]`` and the kernel starts at row 1 of the caller's arrays; the
+    last block of rows is ragged and masked by the live row count; Y keeps
+    its B rows, so the last bank tile is ragged and its missing models read
+    sign 0. Only (B,)- and (B, D)-sized state is padded to whole bank
+    tiles; a Y of another dtype is cast to f32. Otherwise (X's lanes to pad
+    to a multiple of 128, an X of another dtype, or
+    ``stream_dtype="bf16"``) X and Y are copied once, in the stream dtype,
+    as the zero-padded stream without its seed row. The results are those
+    of the zero-padded stream, bit for bit.
     cs: scalar or
     (B,) per-model C (traced — a C sweep reuses one compilation). Starts from
     ``balls`` (a Ball stacked on a leading B axis) if given, else initializes
@@ -606,33 +650,30 @@ def streamsvm_fit_many(
     residency, b_tile = plan_bank_engine(
         b, d, block_n=block_n, b_tile=b_tile, stream_dtype=stream_dtype,
         lookahead_max=l_max, bank_resident=bank_resident,
-        vmem_budget_bytes=vmem_budget_bytes,
+        vmem_budget_bytes=vmem_budget_bytes, x_dtype=X.dtype,
     )
+    skip = 1 if balls is None else 0
     if balls is None:
         w0 = Y[:, 0:1] * X[0][None, :]
         r0 = jnp.zeros((b,), jnp.float32)
         xi20, m0 = gain, jnp.ones((b,), jnp.float32)
-        X, Y = X[1:], Y[:, 1:]
-        n -= 1
     else:
         w0, r0, xi20, m0 = balls.w, balls.r, balls.xi2, balls.m
-    if n == 0:  # nothing (left) to stream — the initial state IS the answer
+    if n == skip:  # nothing (left) to stream — the initial state IS the answer
         return Ball(
             w=w0.astype(jnp.float32),
             r=jnp.broadcast_to(jnp.asarray(r0, jnp.float32), (b,)),
             xi2=jnp.broadcast_to(jnp.asarray(xi20, jnp.float32), (b,)),
             m=jnp.broadcast_to(jnp.asarray(m0, jnp.int32), (b,)),
         )
-    # Pad models to a whole number of bank tiles (tiles themselves to the f32
-    # sublane multiple of 8); padded rows carry zero signs, C=1, L=1 and an
-    # infinite starting radius — they never "violate", so they absorb nothing
-    # and (in lookahead mode) never buffer or flush — and are sliced off
-    # below.
+    # Pad the state to a whole number of bank tiles (tiles themselves to the
+    # f32 sublane multiple of 8); padded models carry C=1, L=1 and an
+    # infinite starting radius, read sign 0 from the kernel, never
+    # "violate", so they absorb nothing (in lookahead mode never buffer or
+    # flush), and are sliced off below.
     bt, _ = bank_tiling(b, b_tile)
     bp = -(-b // bt) * bt
     live = jnp.arange(bp) < b
-    Xp = _pad_to(_pad_to(X.astype(jnp.float32), 128, 1), block_n, 0)
-    Yp = _pad_to(_pad_to(Y.astype(jnp.float32), block_n, 1), bp, 0)
     W0p = _pad_to(_pad_to(w0.astype(jnp.float32), 128, 1), bp, 0)
     pad1 = lambda v: _pad_to(
         jnp.broadcast_to(jnp.asarray(v, jnp.float32), (b,)), bp, 0
@@ -644,9 +685,23 @@ def streamsvm_fit_many(
     else:
         l_arr = None
         l_max = None
+    # The stream is read in place where the kernel can take X as it is. One
+    # that has to be copied anyway (lanes to pad, a dtype to cast) is copied
+    # as the kernel reads it fastest: rows past the seed, zeros past the
+    # live rows, whole blocks and whole bank tiles.
+    sdt = jnp.dtype(stream_dtype or jnp.float32)
+    in_place = reads_in_place(d, X.dtype, sdt)
+    if not in_place:
+        X, Y = X[skip:], Y[:, skip:].astype(sdt)
+        if n_valid is not None:
+            rows = jnp.arange(n - skip)
+            X = jnp.where(rows[:, None] < n_valid - skip, X, 0)
+            Y = jnp.where(rows[None, :] < n_valid - skip, Y, 0)
+        X = _pad_to(_pad_to(X.astype(sdt), 128, 1), block_n, 0)
+        Y = _pad_to(_pad_to(Y, block_n, 1), bp, 0)
     W, r, xi2, m = streamsvm_scan_many_pallas(
-        Xp,
-        Yp,
+        X,
+        Y,
         W0p,
         jnp.where(live, pad1(r0), jnp.inf),
         pad1(xi20),
@@ -655,7 +710,9 @@ def streamsvm_fit_many(
         jnp.where(live, pad1(gain), 1.0),
         lookahead=l_arr,
         lookahead_max=l_max,
-        n_valid=n,
+        n_valid=(n if n_valid is None else n_valid) - skip,
+        in_place=in_place,
+        skip=skip if in_place else 0,
         block_n=block_n,
         b_tile=bt,
         stream_dtype=stream_dtype,

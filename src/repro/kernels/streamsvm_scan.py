@@ -66,8 +66,15 @@ boundaries, with a final partial flush on the last grid step (same
 boundary-flush semantics as fit_chunked).
 
 Stream tiles may be bf16 (``X``/``Y`` dtype is whatever the caller DMAs in —
-see ops.py's ``stream_dtype`` policy); the bank, scalar state, and every
-accumulator stay f32.
+see ops.py's ``stream_dtype`` policy; a stream read in place is f32); the
+bank, scalar state, and every accumulator stay f32.
+
+The bank engine reads the caller's stream and signs in place: its blocks
+start at row 1 of an array whose row 0 seeded the state, its last row block
+and last bank tile may be ragged, and rows past the live count and models
+past Y's rows read 0.0 — the values a zero-padded copy would hold, so the
+results are that copy's, bit for bit (``_kernel_many``; ops.py copies a
+stream it must pad or cast anyway, and drops the seed row in that copy).
 
 Bank residency (``bank_resident``): the bank, its state slabs and the
 lookahead windows live in HBM buffers (aliased pallas_call inputs->outputs,
@@ -188,8 +195,7 @@ def _kernel(
         # Row j is read by sublane from the refs and by a one-hot lane sum
         # from the values (exact: every other term is 0.0). Rows past
         # n_valid AND rows with label sign 0 are inert: sign-0 rows are the
-        # stream-padding contract (fit_bank_sharded pads ragged shard
-        # remainders with them), distinct from a genuine zero FEATURE row,
+        # stream-padding contract, distinct from a genuine zero FEATURE row,
         # which is a legitimate slack-only point.
         hit = lane == j
         grow = gram_ref[pl.ds(j, 1), :]  # (1, block_n) Gram row j
@@ -254,7 +260,7 @@ def _count_slab(c):
     return jnp.broadcast_to(c, (c.shape[0], STATE_LANES))
 
 
-def _bank_flush(w_ref, r, xi2, g, cnt, buf, fmask, x_ref, ys, c_inv, gain):
+def _bank_flush(w_ref, r, xi2, g, cnt, buf, fmask, x_at, ys, c_inv, gain):
     """Farthest-first flush of the lookahead buffers of the masked models.
 
     Vectorized over the b_tile model rows: up to L_max greedy steps, each
@@ -265,7 +271,7 @@ def _bank_flush(w_ref, r, xi2, g, cnt, buf, fmask, x_ref, ys, c_inv, gain):
     vregs); the centers are updated in place in ``w_ref``. ``g`` (the
     maintained <w, y x_k> for the rest of the current block) picks up a
     rank-1 correction per absorb via one (b_tile, D) x (D, block_n) matmul
-    against the stream tile ``x_ref``.
+    against the stream tile (``x_at(c)``: its columns ``c`` in f32).
     Returns the updated (r, xi2, g, cnt) (m is counted at buffer-push time,
     not here).
     """
@@ -301,10 +307,7 @@ def _bank_flush(w_ref, r, xi2, g, cnt, buf, fmask, x_ref, ys, c_inv, gain):
         r = jnp.where(act, r + 0.5 * (dfar - r), r)
         xi2 = xi2 * one_s**2 + s**2 * gain
         # <w', y_bk x_k> = (1-s) g + s y_bk <pfar, x_k>
-        pg = _dot_nt(
-            lambda c: pfar[:, c], lambda c: x_ref[:, c].astype(jnp.float32),
-            pfar.shape[1],
-        )  # (bt, block_n)
+        pg = _dot_nt(lambda c: pfar[:, c], x_at, pfar.shape[1])  # (bt, bn)
         g = one_s * g + s * (ys * pg)
         # remove the absorbed slot; if the farthest point was enclosed, every
         # remaining buffered point is too — drop the whole window.
@@ -321,8 +324,9 @@ def _bank_flush(w_ref, r, xi2, g, cnt, buf, fmask, x_ref, ys, c_inv, gain):
 
 
 def _block_update(
-    x_ref,  # (block_n, D) stream tile ref (f32 or bf16)
-    ys_ref,  # (b_tile, block_n) per-model label-sign tile (f32 or bf16)
+    x_at,  # c -> (block_n, |c|) f32: the block's stream rows, columns c
+    x_row,  # jr -> (1, D) f32: stream row jr of the block (Algorithm 2)
+    ys_ref,  # (b_tile, block_n) f32 per-model label-sign tile
     w_ref,  # (b_tile, D) f32 ref view: the resident bank tile, updated here
     gram_ref,  # (block_n, block_n) f32 VMEM scratch for the block Gram
     band_ref,  # (2 * _rows_per_step(block_n), block_n, 128) f32 (or None)
@@ -334,7 +338,7 @@ def _block_update(
     gain,  # (b_tile, 1) f32 slack gain
     l_arr,  # (b_tile, 1) int32 per-model L (None for Algorithm 1)
     row0,  # traced int: stream index of the block's first row
-    n_valid,  # traced int: rows >= n_valid are padding
+    n_valid,  # traced int: rows >= n_valid are past the live stream
     is_last_block,  # traced bool: final data block (lookahead boundary flush)
     *,
     block_n: int,
@@ -350,14 +354,14 @@ def _block_update(
     tile (Algorithm 1 widens them to lane-replicated (b_tile, 128)).
     The row loop reads row jr without dynamic value slicing (which Mosaic
     does not lower): the Gram row is a sublane read of ``gram_ref``, stream
-    row jr a sublane read of ``x_ref``, and the (b_tile,) columns ``g[:, jr]``
+    row jr a sublane read (``x_row``), and the (b_tile,) columns ``g[:, jr]``
     and ``ys[:, jr]`` are one-hot lane sums against the column iota — exact
     in f32, since every other term is 0.0. Writes the new centers into
     ``w_ref`` and returns ``(r, xi2, wsq, m, cnt)`` (cnt None for
     Algorithm 1). The stream tile and the bank tile are re-read from their
-    refs at each use rather than held as values across the row loop, which
-    would make Mosaic keep a (block_n, D) and a (b_tile, D) copy in VMEM;
-    so are the signs, from ``ys_ref``.
+    refs at each use (``x_at`` reads a D chunk) rather than held as values
+    across the row loop, which would make Mosaic keep a (block_n, D) and a
+    (b_tile, D) copy in VMEM; so are the signs, from ``ys_ref``.
 
     Algorithm 1's loop runs ``_rows_per_step`` rows a step and carries each
     row's g column and signs into the step that uses it: at the start
@@ -373,8 +377,7 @@ def _block_update(
     ahead lie past the block: their one-hot masks are all false and the band
     reads are clamped to the block, and no row uses them.
     """
-    d = x_ref.shape[1]
-    x_at = lambda c: x_ref[:, c].astype(jnp.float32)  # bf16 tiles upcast here
+    d = w_ref.shape[1]
     # One block Gram of the *unsigned* rows, shared by every model (signs are
     # re-applied per model as rank-1 outer factors), plus the tile/block inner
     # products — the only O(D) work in the block, all MXU.
@@ -428,8 +431,8 @@ def _block_update(
                 gj, yj = cols[a]
                 gjj = band(0, jr)  # G[j, j]
                 # Inert per model: rows past n_valid, and rows whose sign is
-                # 0 for that model (fit_bank_sharded's stream padding; padded
-                # bank rows).
+                # 0 for that model (a caller's stream padding; padded bank
+                # rows).
                 live = jnp.logical_and(yj != 0.0, row0 + jr < n_valid)
                 d2 = wsq - 2.0 * gj + gjj + xi2 + c_inv
                 d = jnp.sqrt(jnp.maximum(d2, 1e-12))
@@ -494,8 +497,8 @@ def _block_update(
         """Row jr: its one-hot lane mask, Gram row, G_jj, g[:, jr], y[:, jr],
         and which models it is live for. Sign-0 inertness is PER MODEL ROW:
         a row whose sign is 0 for model b never violates model b (the
-        stream-padding contract used by fit_bank_sharded's ragged-remainder
-        rows, and what keeps padded *bank* rows from absorbing anything)."""
+        stream-padding contract, and what keeps padded *bank* rows from
+        absorbing anything)."""
         hit = col_ids == jr
         grow = gram_ref[pl.ds(jr, 1), :]  # (1, block_n)
         gjj = jnp.sum(
@@ -510,7 +513,7 @@ def _block_update(
 
     def flush(fmask, g, r, xi2, wsq, cnt):
         r, xi2, g, cnt = _bank_flush(
-            w_ref, r, xi2, g, cnt, buf_ref[...], fmask, x_ref, ys, c_inv,
+            w_ref, r, xi2, g, cnt, buf_ref[...], fmask, x_at, ys, c_inv,
             gain,
         )
         w = w_ref[...]
@@ -527,7 +530,7 @@ def _block_update(
         d = jnp.sqrt(jnp.maximum(d2, 1e-12))
         violate = jnp.logical_and(d >= r, live)
         # push the signed row into each violated model's window
-        p = yj * x_ref[pl.ds(jr, 1), :].astype(jnp.float32)  # (b_tile, D)
+        p = yj * x_row(jr)  # (b_tile, D)
         put = jnp.logical_and(violate[None], slot == cnt[None])
         buf_ref[...] = jnp.where(put, p[None], buf_ref[...])
         cnt = cnt + violate.astype(jnp.int32)
@@ -558,30 +561,45 @@ def _block_update(
 
 
 def _kernel_many(
-    x_ref,  # (block_n, D) stream tile (raw rows; f32 or bf16)
-    ys_ref,  # (b_tile, block_n) per-model label-sign tile
-    p_ref,  # (b_tile, 3) per-model parameters [c_inv, gain, L]
-    nv_ref,  # (1, 1) number of valid rows (N before padding)
-    *refs,  # aliased HBM inputs, HBM outputs, VMEM slots, DMA sems, Gram
+    *refs,  # inputs, aliased HBM inputs, HBM outputs, VMEM scratch
     block_n: int,
     b_tile: int,
     lookahead_max: int | None,
     n_blocks: int,
     n_btiles: int,
     n_slots: int,
+    in_place: bool,
+    skip: int,
+    n_models: int,
 ):
     """The bank engine: state in HBM, tiles staged in VMEM slots.
 
-    ``refs`` unpacks as ``n_arrays`` aliased input refs (unused — the
+    ``refs`` unpacks as the inputs: the stream tile x (block_n, D) of X's
+    rows block_n * i .., with ``skip`` the tile xn (rows, D) that holds the
+    first row of the next one; the sign tile (b_tile, block_n) of Y, with
+    ``skip`` the tile (b_tile, 128) that holds the next block's first
+    column; the (b_tile, 3) parameters [c_inv, gain, L] and the (1, 1) count
+    of live rows. Then ``n_arrays`` aliased input refs (unused — the
     aliased OUTPUT refs address the same buffers and carry the initial
     state), then ``n_arrays`` HBM output refs [bank (B, D) f32,
     st (B, 128) f32 slabs (r, xi2, wsq, 0, ...), m (B, 128) i32, and with
     lookahead cnt (B, 128) i32 + buf (L_max, B, D) f32], then ``n_arrays``
     VMEM slot buffers with a leading ``n_slots`` axis, then one
     DMA-semaphore array of shape (n_arrays, 2, 2) = (array, in/out, slot),
-    then the (block_n, block_n) Gram scratch and, for Algorithm 1, the
-    (2 * _rows_per_step(block_n), block_n, 128) Gram band. Every tile is a sublane
-    slab (rows tile*b_tile ...), so each DMA is 8-aligned.
+    then the (block_n, block_n) Gram scratch, for Algorithm 1 the
+    (2 * _rows_per_step(block_n), block_n, 128) Gram band, and ``in_place``
+    last the (b_tile, block_n) f32 scratch of the block's signs. Every tile
+    is a sublane slab (rows tile*b_tile ...), so each DMA is 8-aligned.
+
+    ``in_place``: the stream and the signs are the caller's arrays, read in
+    place, and the block is rows ``skip + block_n * i ..`` of them. With
+    ``skip`` (row 0 seeded the state) the block starts one row into its
+    tiles, so each D chunk of X is rolled up one sublane and its last row
+    taken from the next tile, and the signs one lane. Rows at or past the
+    live count and models at or past ``n_models`` read 0.0 (as a
+    zero-padded copy would hold), so whatever a ragged tile holds beyond the
+    arrays, even a NaN, never reaches the arithmetic. Otherwise X and Y are
+    such a copy already, and are read as they are.
 
     ``n_slots == n_btiles`` is the VMEM-resident layout: every tile owns a
     slot, loads on the first data block and writes back after the last.
@@ -590,6 +608,11 @@ def _kernel_many(
     before compute on step t and writing step t's tile back async, waited at
     t+1 (hazard argument in the module docstring).
     """
+    x_ref, refs = refs[0], refs[1:]
+    xn_ref, refs = (refs[0], refs[1:]) if skip else (None, refs)
+    ys_ref, refs = refs[0], refs[1:]
+    yn_ref, refs = (refs[0], refs[1:]) if skip else (None, refs)
+    p_ref, nv_ref, refs = refs[0], refs[1], refs[2:]
     n_arrays = 3 if lookahead_max is None else 5
     hbm = refs[n_arrays : 2 * n_arrays]  # aliased outputs == the live state
     slots = refs[2 * n_arrays : 3 * n_arrays]
@@ -662,17 +685,60 @@ def _kernel_many(
             jnp.sum(bank[slot] ** 2, axis=1, keepdims=True),
         )
 
+    # The block's rows and signs, realigned and masked (docstring above).
+    row0 = i * block_n
+    n_valid = nv_ref[0, 0]
+    live = n_valid - row0  # live rows of this block (may be < 1)
+
+    def x_at(c):
+        x = x_ref[:, c].astype(jnp.float32)
+        if not in_place:
+            return x
+        rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        if skip:
+            nxt = xn_ref[:, c].astype(jnp.float32)[0:1]
+            x = jnp.where(rows == block_n - 1, nxt,
+                          pltpu.roll(x, block_n - 1, 0))
+        return jnp.where(rows < live, x, 0.0)
+
+    def x_row(jr):  # rows past the live count are never pushed
+        if not skip:
+            return x_ref[pl.ds(jr, 1), :].astype(jnp.float32)
+        row = x_ref[pl.ds(jnp.minimum(jr + 1, block_n - 1), 1), :]
+        nxt = xn_ref[...].astype(jnp.float32)[0:1]
+        return jnp.where(jr == block_n - 1, nxt, row.astype(jnp.float32))
+
+    if in_place:
+        ys = ys_ref[...]
+        cols = jax.lax.broadcasted_iota(jnp.int32, ys.shape, 1)
+        if skip:
+            if block_n % STATE_LANES == 0:  # the next block starts a lane tile
+                nxt = yn_ref[:, 0:1]
+            else:
+                lane = jax.lax.broadcasted_iota(jnp.int32, yn_ref.shape, 1)
+                at = jax.lax.rem((i + 1) * block_n, STATE_LANES)
+                nxt = jnp.sum(jnp.where(lane == at, yn_ref[...], 0.0), axis=1,
+                              keepdims=True)
+            ys = jnp.where(cols == block_n - 1, nxt,
+                           pltpu.roll(ys, block_n - 1, 1))
+        models = jax.lax.broadcasted_iota(jnp.int32, ys.shape, 0)
+        ys_ref = refs[-1]
+        ys_ref[...] = jnp.where(
+            jnp.logical_and(cols < live, models < n_models - j * b_tile),
+            ys, 0.0,
+        )
+
     st = st_slots[slot]
     params = p_ref[...]
     lookahead = lookahead_max is not None
     r, xi2, wsq, m, cnt = _block_update(
-        x_ref, ys_ref, bank.at[slot], gram_ref, band_ref,
+        x_at, x_row, ys_ref, bank.at[slot], gram_ref, band_ref,
         st[:, 0:1], st[:, 1:2], st[:, 2:3], m_slots[slot][:, 0:1],
         slots[3][slot][:, 0:1] if lookahead else None,
         slots[4].at[slot] if lookahead else None,
         params[:, 0:1], params[:, 1:2],
         params[:, 2:3].astype(jnp.int32) if lookahead else None,
-        i * block_n, nv_ref[0, 0], i == n_blocks - 1,
+        row0, n_valid, i == n_blocks - 1,
         block_n=block_n, b_tile=b_tile, lookahead_max=lookahead_max,
     )
     st_slots[slot] = _state_slab(r, xi2, wsq)
@@ -765,7 +831,9 @@ def streamsvm_scan_many_pallas(
     *,
     lookahead: jax.Array | None = None,
     lookahead_max: int | None = None,
-    n_valid: int | None = None,
+    n_valid=None,
+    in_place: bool = True,
+    skip: int = 0,
     block_n: int = 256,
     b_tile: int | None = None,
     stream_dtype=None,
@@ -774,23 +842,30 @@ def streamsvm_scan_many_pallas(
 ):
     """One data pass updating a bank of B balls (the tiled multi-ball engine).
 
-    X: (N, D) stream (raw rows, no label signs) — D padded to a multiple of
-    128, N to a multiple of block_n; rows >= n_valid are ignored.
-    Y: (B, N) per-model label signs in {-1, +1}. Sign 0 marks an inert row
-    for that model — padded model lanes, and padded stream rows (the ragged
-    shard remainders fit_bank_sharded appends) never violate, absorb or
-    buffer anything.
+    X: (skip + N, D) stream (raw rows, no label signs), D a multiple of 128.
+    Y: (B_y, skip + N) per-model label signs in {-1, +1}, B_y <= B (models
+    past B_y are padding: a ragged last bank tile). The pass reads X and Y
+    in place: it runs over their rows ``skip ..`` (``skip=1``: row 0 seeded
+    the state and is consumed) in blocks of ``block_n`` rows, the last of
+    which may be ragged; ``n_valid`` (traced; default N) counts the live
+    rows, and later rows are ignored whatever they hold. ``in_place=False``
+    says X and Y are instead a zero-padded copy of the rows to stream (the
+    one ops.py makes of a stream it must pad or cast anyway): N a whole
+    number of blocks and B_y = B, read as they are. Sign 0 marks an inert
+    row for that model: it never violates, absorbs or buffers anything.
     W0/(r0, xi20, c_inv, m0): per-model starting state, shapes (B, D)/(B,).
     gain: per-model slack gain (defaults to c_inv — the "exact" variant).
     lookahead/lookahead_max: per-model (B,) int32 Algorithm-2 window sizes
     plus their static max — None runs Algorithm 1. Partial windows are
     flushed on the last grid step.
     b_tile: models per bank tile (must divide B; defaults to B, one tile).
-    The grid is (N/block_n, B/b_tile) with the DATA axis outer, so every
-    stream tile is DMA'd from HBM once and revisited by all bank tiles.
+    The grid is (ceil(rows / block_n), B/b_tile) with the DATA axis
+    outer, so every stream tile is DMA'd from HBM once and revisited by all
+    bank tiles.
     stream_dtype: dtype the (block_n, D) stream and (b_tile, block_n) sign
-    tiles are DMA'd as (e.g. jnp.bfloat16 halves stream HBM traffic); bank,
-    scalar state, and accumulators stay f32.
+    tiles are DMA'd as (e.g. jnp.bfloat16 halves stream HBM traffic; only a
+    copy, ``in_place=False``, may be bf16); bank, scalar state, and
+    accumulators stay f32.
     bank_resident: "vmem" gives every bank tile its own VMEM slot, loaded
     once and written back once; "hbm" double-buffers (b_tile, D) tiles
     through 2 VMEM slots (see the module docstring), per-step VMEM working
@@ -799,17 +874,27 @@ def streamsvm_scan_many_pallas(
 
     Returns (W, r, xi2, m) with leading axis B.
     """
-    n, d = X.shape
-    b = Y.shape[0]
-    if Y.shape != (b, n):
+    n_x, d = X.shape
+    b = W0.shape[0]
+    b_y, n_y = Y.shape
+    if block_n < 8 or block_n % 8:
         raise ValueError(
-            f"Y must be (B, N) sign rows matching X: got Y.shape={Y.shape}, "
-            f"X.shape={X.shape}"
+            f"block_n={block_n} must be a positive multiple of 8 (the stream "
+            "tile is a whole number of sublane tiles)"
         )
-    if n % block_n != 0:
+    n = n_y - skip  # rows to stream
+    n_blocks = -(-n // block_n)
+    if skip not in (0, 1) or n < 1 or (not in_place and skip):
         raise ValueError(
-            f"N={n} must be a multiple of block_n={block_n} (pad the stream; "
-            "ops.streamsvm_fit_many does this)"
+            f"skip={skip} must be 0 or 1 (0 for a copy) and leave rows of "
+            f"Y.shape={Y.shape}"
+        )
+    if n_x != n_y or b_y > b or not in_place and (
+            n % block_n or b_y != b):
+        raise ValueError(
+            f"Y must be (B, N) sign rows matching X and the state: got "
+            f"Y.shape={Y.shape}, X.shape={X.shape}, B={b} (a copy is a "
+            f"whole number of {block_n}-row blocks and of models)"
         )
     if b_tile is None:
         b_tile = b
@@ -829,9 +914,15 @@ def streamsvm_scan_many_pallas(
             "'hbm' (ops.streamsvm_fit_many resolves 'auto' before calling "
             "the kernel)"
         )
-    n_blocks = n // block_n
     n_btiles = b // b_tile
     stream_dtype = jnp.float32 if stream_dtype is None else stream_dtype
+    if in_place and jnp.dtype(stream_dtype) != jnp.float32:
+        raise ValueError(
+            f"stream_dtype={stream_dtype!r}: only an f32 stream is read in "
+            "place (pass a copy in the stream dtype with in_place=False)"
+        )
+    X = X.astype(stream_dtype)
+    Y = Y.astype(stream_dtype)
 
     W0 = W0.reshape(b, d).astype(jnp.float32)
     c_inv = jnp.broadcast_to(jnp.asarray(c_inv, jnp.float32), (b,))
@@ -845,7 +936,8 @@ def streamsvm_scan_many_pallas(
     params = jnp.concatenate(
         [c_inv[:, None], gain[:, None], l_arr.astype(jnp.float32)], axis=1
     )  # (B, 3): [c_inv, gain, L]; L < 2**24 is exact in f32
-    nv = jnp.array([[n if n_valid is None else n_valid]], jnp.int32)
+    nv = jnp.asarray(n if n_valid is None else n_valid,
+                     jnp.int32).reshape(1, 1)
 
     # The live state, in HBM, aliased input -> output so the kernel
     # updates it in place. Per-model scalars are (B, 128) slabs (see
@@ -867,6 +959,40 @@ def streamsvm_scan_many_pallas(
         pltpu.VMEM((n_slots,) + a.shape[:-2] + (b_tile, a.shape[-1]), a.dtype)
         for a in state
     ]
+    # The stream tile and the sign tile; with ``skip`` also the tiles that
+    # hold the next block's first row and column (clamped to the arrays on
+    # the last block, whose last row is then past the stream).
+    ins = [X]
+    in_specs = [
+        # The stream tile ignores the (inner) bank axis, so Pallas keeps
+        # it resident across all bank tiles of a data block — the
+        # data-major reuse the 2-D grid exists for.
+        pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
+    ]
+    if skip:
+        rows = math.gcd(block_n, 8 * 4 // jnp.dtype(stream_dtype).itemsize)
+        last_rows = -(-n_x // rows) - 1
+        ins.append(X)
+        in_specs.append(pl.BlockSpec(
+            (rows, d),
+            lambda i, j: (jnp.minimum((i + 1) * (block_n // rows), last_rows),
+                          0),
+        ))
+    ins.append(Y)
+    in_specs.append(pl.BlockSpec((b_tile, block_n), lambda i, j: (j, i)))
+    if skip:
+        last_lanes = -(-n_y // STATE_LANES) - 1
+        ins.append(Y)
+        in_specs.append(pl.BlockSpec(
+            (b_tile, STATE_LANES),
+            lambda i, j: (j, jnp.minimum((i + 1) * block_n // STATE_LANES,
+                                         last_lanes)),
+        ))
+    ins += [params, nv]
+    in_specs += [
+        pl.BlockSpec((b_tile, 3), lambda i, j: (j, 0)),
+        pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+    ]
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     outs = pl.pallas_call(
         functools.partial(
@@ -877,17 +1003,12 @@ def streamsvm_scan_many_pallas(
             n_blocks=n_blocks,
             n_btiles=n_btiles,
             n_slots=n_slots,
+            in_place=in_place,
+            skip=skip,
+            n_models=b_y,
         ),
         grid=(n_blocks, n_btiles),
-        in_specs=[
-            # The stream tile ignores the (inner) bank axis, so Pallas keeps
-            # it resident across all bank tiles of a data block — the
-            # data-major reuse the 2-D grid exists for.
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((b_tile, block_n), lambda i, j: (j, i)),
-            pl.BlockSpec((b_tile, 3), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ] + [hbm_spec] * n_arrays,
+        in_specs=in_specs + [hbm_spec] * n_arrays,
         out_specs=[hbm_spec] * n_arrays,
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state],
         scratch_shapes=slot_bufs + [
@@ -898,11 +1019,12 @@ def streamsvm_scan_many_pallas(
                 (2 * _rows_per_step(block_n), block_n, STATE_LANES),
                 jnp.float32,
             ),
-        ]),
-        input_output_aliases={4 + a: a for a in range(n_arrays)},
+        ]) + ([pltpu.VMEM((b_tile, block_n), jnp.float32)] if in_place
+               else []),
+        input_output_aliases={len(ins) + a: a for a in range(n_arrays)},
         interpret=interpret,
         name="streamsvm_scan_many",
-    )(X.astype(stream_dtype), Y.astype(stream_dtype), params, nv, *state)
+    )(*ins, *state)
     w_out, st_out, m_out = outs[0], outs[1], outs[2]
     return w_out, st_out[:, 0], st_out[:, 1], m_out[:, 0]
 

@@ -412,7 +412,7 @@ def test_scan_wrapper_validates_tiling():
     with pytest.raises(ValueError, match="b_tile"):
         streamsvm_scan_many_pallas(X, Y, W0, z, z, z, z, block_n=128, b_tile=3)
     with pytest.raises(ValueError, match="block_n"):
-        streamsvm_scan_many_pallas(X[:100], Y[:, :100], W0, z, z, z, z, block_n=64)
+        streamsvm_scan_many_pallas(X[:100], Y[:, :100], W0, z, z, z, z, block_n=60)
     with pytest.raises(ValueError, match="lookahead_max"):
         streamsvm_scan_many_pallas(
             X, Y, W0, z, z, z, z, block_n=128,

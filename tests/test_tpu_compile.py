@@ -119,3 +119,53 @@ def test_gram_compiles_for_v5e(one_chip, epilogue):
         one_chip, (1000, D), (B, D),
     )
     assert "tpu_custom_call" in text
+
+
+def _compile_four_chip_cell_fit(topo, stream_dtype):
+    """``_sharded_fits`` compiled for the ImageNet-fc7 training deployment
+    on a v5e 2x2 host: 1,281,164 rows x 4096 f32 split by rows over the
+    four chips, B = 3000 (1000 classes x 3 C) in "auto" residency."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.distributed import _sharded_fits
+
+    n, d, b = 1_281_164, 4096, 3000
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    shape = lambda s, spec: jax.ShapeDtypeStruct(
+        s, jnp.float32, sharding=NamedSharding(mesh, spec))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = _sharded_fits.lower(
+            shape((n, d), P("data")), shape((b, n), P(None, "data")),
+            shape((b,), P()),
+            mesh=mesh, axes=("data",), n_rows=n, variant="exact",
+            lookahead=None, block_n=256, b_tile=None,
+            stream_dtype=stream_dtype, bank_resident="auto", interpret=False,
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled.memory_analysis()
+
+
+def test_sharded_fit_of_the_four_chip_cell_reads_the_stream_in_place(topo):
+    """The stream and signs are 9.09 GB a chip; the fit's own temporaries
+    must stay under 1 GB a chip (a padded copy of the shard's stream and
+    signs would be about 9 GB, past the chip's memory)."""
+    mem = _compile_four_chip_cell_fit(topo, None)
+    assert mem.temp_size_in_bytes < 1e9
+
+
+def test_the_four_chip_cells_bf16_control_fits_the_chip(topo):
+    """The cell's control, ``stream_dtype="bf16"``, copies the shard's
+    stream and signs in bf16 (2.6 GB and 1.9 GB a chip; the compiler holds
+    the signs' slice and their padded copy at once, 6.5 GB in all). It
+    must compile next to the 9.09 GB of f32 data: copies of the signs in
+    f32 would take 3.9 GB more."""
+    mem = _compile_four_chip_cell_fit(topo, "bf16")
+    assert mem.temp_size_in_bytes < 7e9
