@@ -515,7 +515,10 @@ def fit_bank_sharded(
     ready (points where the fit waits anyway):
 
     - ``fit.shards``: the mesh program, dispatch until its stacked (S, B,
-      ...) banks are ready;
+      ...) banks are ready. Its arguments ``gram_fills`` and
+      ``tile_visits`` count a shard's data blocks, where the engine fills
+      the block Gram, and its (block, bank tile) visits
+      (``kernels.ops.bank_engine_grid``);
     - ``fit.gather``: the stacked banks to the host;
     - ``fit.fold``: ``fold_merge`` of the live shards on one device;
     - ``fit.place``: the folded bank replicated on the mesh.
@@ -569,7 +572,16 @@ def fit_bank_sharded(
         # folded with; then replicate. Compiled into the mesh program, XLA
         # may fuse the merge arithmetic differently, which on a TPU moves
         # the last bits of the result. The host hop copies bits exactly.
-        with jax.profiler.TraceAnnotation("fit.shards"):
+        from repro.kernels.ops import bank_engine_grid  # lazy: module cycle
+
+        blocks, tiles = bank_engine_grid(
+            shard_n, b, d, variant=variant, lookahead=lookahead,
+            block_n=block_n, b_tile=b_tile, stream_dtype=stream_dtype,
+            bank_resident=bank_resident, x_dtype=X.dtype,
+        )
+        with jax.profiler.TraceAnnotation(
+            "fit.shards", gram_fills=blocks, tile_visits=blocks * tiles
+        ):
             stacked = jax.block_until_ready(fits(X, Y, cs))
         with jax.profiler.TraceAnnotation("fit.gather"):
             host = jax.tree.map(lambda v: np.asarray(v)[:n_live], stacked)

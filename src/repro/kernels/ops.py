@@ -487,6 +487,36 @@ def plan_bank_engine(
     return residency, b_tile
 
 
+def bank_engine_grid(
+    n: int,
+    b: int,
+    d: int,
+    *,
+    variant: str = "exact",
+    lookahead=None,
+    block_n: int = 256,
+    b_tile: int | None = None,
+    stream_dtype=None,
+    bank_resident: str = "auto",
+    x_dtype=jnp.float32,
+) -> tuple[int, int]:
+    """``(data blocks, bank tiles)``: the training engine's grid when
+    ``streamsvm_fit_many`` fits B models on an (n, d) stream from scratch
+    (row 0 seeds the bank; the grid streams the other n - 1 rows), tiled
+    by the same plan. The engine fills the block Gram once per data block
+    and visits each block once per bank tile."""
+    l_max = None
+    if variant in ("lookahead", "lookahead-paper"):
+        lookahead = 1 if lookahead is None else lookahead
+        l_max = lookahead if isinstance(lookahead, int) else max(lookahead)
+    stream_dtype = _resolve_stream_dtype(stream_dtype)
+    _, b_tile = plan_bank_engine(
+        b, d, block_n=block_n, b_tile=b_tile, stream_dtype=stream_dtype,
+        lookahead_max=l_max, bank_resident=bank_resident, x_dtype=x_dtype,
+    )
+    return -(-(n - 1) // block_n), bank_tiling(b, b_tile)[1]
+
+
 def _pad_to(x, mult, axis):
     size = x.shape[axis]
     pad = (-size) % mult

@@ -17,7 +17,8 @@ stream; each grid step:
 
 Per-block cost: one (block_n x D x block_n) matmul + block_n * O(block_n + D)
 vector work — MXU-friendly, and exactly equal in result to the reference
-scan (tests sweep shapes/dtypes against ref.py).
+scan (tests sweep shapes/dtypes against ref.py). The bank engine below pays
+that Gram once per data block, not once per bank tile.
 
 Scalar state is carried in an SMEM (4,)-vector: [r, xi2, |w|^2, m].
 
@@ -27,13 +28,15 @@ is the same pass generalized to a BANK of B independent models on a 2-D grid
 is outer and the bank-tile axis inner, so each (block_n, D) stream tile is
 fetched from HBM exactly once (its BlockSpec index ignores the bank axis, so
 Pallas elides the re-copy across the inner iterations) and is revisited by
-every (b_tile, D) tile of the bank. Per (i, j) step: one shared unsigned
-block Gram + one tile/block matmul feed a fori_loop whose conditional update
-is vectorized across the b_tile models (one model per sublane row; per-model
-label signs re-applied as rank-1 factors), and the bank tile is updated once
-per block via accumulated (decay, alpha) coefficients — a single
-(b_tile, block_n) x (block_n, D) matmul. B models still cost ONE pass of
-data movement, for arbitrary B.
+every (b_tile, D) tile of the bank. The unsigned block Gram (and Algorithm
+1's band of it) depends on the block alone: the block's first step (j = 0)
+fills it into scratch and the other bank tiles of the block read it there.
+Per (i, j) step: one tile/block matmul and that shared Gram feed a
+fori_loop whose conditional update is vectorized across the b_tile models
+(one model per sublane row; per-model label signs re-applied as rank-1
+factors), and the bank tile is updated once per block via accumulated
+(decay, alpha) coefficients — a single (b_tile, block_n) x (block_n, D)
+matmul. B models still cost ONE pass of data movement, for arbitrary B.
 
 Mosaic lowers no dynamic slice of a value, so the row loop never indexes a
 value at the traced row: the Gram row is a sublane read of a VMEM scratch
@@ -330,6 +333,7 @@ def _block_update(
     w_ref,  # (b_tile, D) f32 ref view: the resident bank tile, updated here
     gram_ref,  # (block_n, block_n) f32 VMEM scratch for the block Gram
     band_ref,  # (2 * _rows_per_step(block_n), block_n, 128) f32 (or None)
+    fill_gram,  # traced bool (or True): the block's first visit fills both
     r, xi2, wsq,  # (b_tile, 1) f32 per-model scalars
     m,  # (b_tile, 1) int32 core-vector counts
     cnt,  # (b_tile, 1) int32 lookahead fill counts (None for Algorithm 1)
@@ -363,6 +367,11 @@ def _block_update(
     across the row loop, which would make Mosaic keep a (block_n, D) and a
     (b_tile, D) copy in VMEM; so are the signs, from ``ys_ref``.
 
+    The Gram (and Algorithm 1's band of it) is the block's alone, the same
+    for every bank tile: it is computed into ``gram_ref`` (and ``band_ref``)
+    only where ``fill_gram`` holds, the block's first visit, and every
+    later visit of the block reads what that one wrote.
+
     Algorithm 1's loop runs ``_rows_per_step`` rows a step and carries each
     row's g column and signs into the step that uses it: at the start
     of a step it lane-sums the next step's columns out of the g the step
@@ -370,18 +379,24 @@ def _block_update(
     ``gk = one_s * gk + (s * y_j) * (y_k * G[j, k])``: the operations, in
     order, that the full-width ``g`` update applies to column k. G[j, k]
     and G_jj are sublane reads of ``band_ref`` (t, k) = G[k, k + t], filled
-    once a block from ``gram_ref``. The chain from a row to the next (d^2,
-    sqrt, divide, s, that correction) thus holds no lane sum; the
-    full-width update of g, which only feeds the column sums a step later,
-    and that of alpha leave it. On a block's last step the columns read
-    ahead lie past the block: their one-hot masks are all false and the band
-    reads are clamped to the block, and no row uses them.
+    with the Gram, once a block, from ``gram_ref``. The chain from a row
+    to the next (d^2, sqrt, divide, s, that correction) thus holds no lane
+    sum; the full-width update of g, which only feeds the column sums a
+    step later, and that of alpha leave it. On a block's last step the
+    columns read ahead lie past the block: their one-hot masks are all
+    false and the band reads are clamped to the block, and no row uses
+    them.
     """
     d = w_ref.shape[1]
+
     # One block Gram of the *unsigned* rows, shared by every model (signs are
-    # re-applied per model as rank-1 outer factors), plus the tile/block inner
-    # products — the only O(D) work in the block, all MXU.
-    gram_ref[...] = _dot_nt(x_at, x_at, d)  # (block_n, block_n)
+    # re-applied per model as rank-1 outer factors) and by every bank tile,
+    # plus the tile/block inner products — the only O(D) work in the block,
+    # all MXU.
+    @pl.when(fill_gram)
+    def _fill_gram():
+        gram_ref[...] = _dot_nt(x_at, x_at, d)  # (block_n, block_n)
+
     h0 = _dot_nt(lambda c: w_ref[:, c], x_at, d)  # (b_tile, block_n): <w_b, x_k>
 
     if lookahead_max is None:
@@ -404,10 +419,14 @@ def _block_update(
         ys_at = lambda: ys_ref[...].astype(jnp.float32)
         # The Gram's band, lane-replicated: band_ref[t, k] = G[k, k + t]
         # (0.0 past the block), so a row reads its entries by sublane.
-        g_rows = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 0)
-        g_cols = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_n), 1)
-        for t in range(band_ref.shape[0]):
-            band_ref[t] = lane_sum(g_cols == g_rows + t, gram_ref[...])
+        @pl.when(fill_gram)
+        def _fill_band():
+            shape = (block_n, block_n)
+            g_rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            g_cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            for t in range(band_ref.shape[0]):
+                band_ref[t] = lane_sum(g_cols == g_rows + t, gram_ref[...])
+
         band = lambda t, k: band_ref[
             t, pl.ds(jnp.minimum(k, block_n - 1), 1), :
         ]
@@ -590,6 +609,8 @@ def _kernel_many(
     (2 * _rows_per_step(block_n), block_n, 128) Gram band, and ``in_place``
     last the (b_tile, block_n) f32 scratch of the block's signs. Every tile
     is a sublane slab (rows tile*b_tile ...), so each DMA is 8-aligned.
+    The Gram and its band are filled on step (i, 0) and read by steps
+    (i, 1 ..) of the same block; with one bank tile every step fills them.
 
     ``in_place``: the stream and the signs are the caller's arrays, read in
     place, and the block is rows ``skip + block_n * i ..`` of them. With
@@ -733,6 +754,7 @@ def _kernel_many(
     lookahead = lookahead_max is not None
     r, xi2, wsq, m, cnt = _block_update(
         x_at, x_row, ys_ref, bank.at[slot], gram_ref, band_ref,
+        True if J == 1 else j == 0,  # the block's first visit fills the Gram
         st[:, 0:1], st[:, 1:2], st[:, 2:3], m_slots[slot][:, 0:1],
         slots[3][slot][:, 0:1] if lookahead else None,
         slots[4].at[slot] if lookahead else None,
@@ -994,6 +1016,9 @@ def streamsvm_scan_many_pallas(
         pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
     ]
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    # Both grid axes stay sequential ("arbitrary", the default): besides the
+    # bank state, the bank axis carries the block Gram and its band in
+    # scratch from step (i, 0) to the steps (i, 1 ..) that read them.
     outs = pl.pallas_call(
         functools.partial(
             _kernel_many,

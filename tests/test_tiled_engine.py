@@ -7,11 +7,14 @@ in-kernel Algorithm 2 must match the plain-python oracle in ref.py across
 L. bf16 stream tiles trade bounded precision for half the stream traffic.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import fit_bank, fit_lookahead, fit_ovr, predict_ovr
+from repro.core.distributed import fit_bank_sharded
 from repro.kernels import streamsvm_fit, streamsvm_fit_many
+from repro.kernels.ops import bank_engine_grid
 from repro.kernels.ref import (
     streamsvm_scan_lookahead_many_ref,
     streamsvm_scan_lookahead_ref,
@@ -48,6 +51,67 @@ def test_tiled_bit_exact_with_single_tile(b, n, d, block_n, b_tile):
     np.testing.assert_array_equal(np.asarray(tiled.r), np.asarray(one.r))
     np.testing.assert_array_equal(np.asarray(tiled.xi2), np.asarray(one.xi2))
     np.testing.assert_array_equal(np.asarray(tiled.m), np.asarray(one.m))
+
+
+@pytest.mark.parametrize("b,n,d,block_n,b_tile", [
+    # read in place (row 0 seeds, so each block starts a row into its tile),
+    # a ragged last block of 43 rows and a last bank tile of 5 models
+    (21, 300, 128, 64, 8),
+    (24, 300, 40, 64, 8),  # a zero-padded copy of the stream
+])
+@pytest.mark.parametrize("bank_resident", ["vmem", "hbm"])
+@pytest.mark.parametrize("variant,lookahead", [
+    ("exact", None), ("lookahead", (3, 1, 7)),
+])
+def test_block_gram_is_shared_by_the_bank_tiles(
+    b, n, d, block_n, b_tile, bank_resident, variant, lookahead
+):
+    """Only a block's first bank tile fills the block Gram (and its band);
+    the block's other tiles read it. A fit of J >= 3 tiles must give, tile
+    by tile, the bits of that tile's models fitted alone (J = 1, where
+    every visit fills the Gram): a Gram left from the previous block or
+    filled at another tile fails it."""
+    X, Y, cs = _bank_data(b, n, d, seed=b * n + d)
+    ls = None if lookahead is None else (lookahead * b)[:b]
+    kw = dict(variant=variant, block_n=block_n, bank_resident=bank_resident)
+    blocks, tiles = bank_engine_grid(n, b, d, lookahead=ls, b_tile=b_tile, **kw)
+    assert (blocks, tiles) == (-(-(n - 1) // block_n), -(-b // b_tile))
+    assert tiles >= 3 and (n - 1) % block_n
+    bank = streamsvm_fit_many(X, Y, cs, lookahead=ls, b_tile=b_tile, **kw)
+    for lo in range(0, b, b_tile):
+        hi = min(lo + b_tile, b)
+        alone = streamsvm_fit_many(
+            X, Y[lo:hi], cs[lo:hi], lookahead=ls and ls[lo:hi], **kw
+        )
+        for field in ("w", "r", "xi2", "m"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(bank, field))[lo:hi],
+                np.asarray(getattr(alone, field)),
+            )
+
+
+def test_mesh_fit_span_counts_gram_fills_and_tile_visits(monkeypatch):
+    """The eager mesh fit's ``fit.shards`` span carries the shard's Gram
+    fills (its data blocks) and tile visits (blocks x bank tiles)."""
+    spans = []
+
+    class Span:
+        def __init__(self, name, **args):
+            spans.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
+    X, Y, cs = _bank_data(21, 300, 128, seed=4)
+    mesh = jax.make_mesh((1,), ("data",))
+    fit_bank_sharded(X, Y, cs, mesh, block_n=64, b_tile=8)
+    args = dict(spans)["fit.shards"]
+    # 299 streamed rows in blocks of 64, three tiles of 8 for 21 models
+    assert args == dict(gram_fills=5, tile_visits=15)
 
 
 def test_tiled_matches_bank_ref_at_8x_tile():
