@@ -19,10 +19,11 @@ import numpy as np
 
 from repro.core import (
     accuracy,
+    bank_take,
     fit,
+    fit_bank,
     fit_bank_sharded,
     fit_c_grid,
-    fit_sharded,
     ovr_signs,
     predict_ovr,
 )
@@ -32,8 +33,7 @@ from repro.data import load_dataset, preprocess_for
 def main():
     Xtr, ytr, Xte, yte = load_dataset("mnist89")
     Xtr, Xte = preprocess_for("mnist89", Xtr, Xte)
-    n = (len(ytr) // 8) * 8
-    Xj, yj = jnp.asarray(Xtr[:n]), jnp.asarray(ytr[:n])
+    Xj, yj = jnp.asarray(Xtr), jnp.asarray(ytr)  # any N: ragged shards pad
     Xt, yt = jnp.asarray(Xte), jnp.asarray(yte)
 
     mesh = jax.make_mesh((8,), ("data",))
@@ -44,7 +44,11 @@ def main():
     t_seq = time.time() - t0
 
     t0 = time.time()
-    ball_dist = fit_sharded(Xj, yj, 10.0, mesh, lookahead=10)
+    ball_dist = bank_take(
+        fit_bank(Xj, yj[None], 10.0, mesh=mesh, variant="lookahead",
+                 lookahead=10),
+        0,
+    )
     t_dist = time.time() - t0
 
     print(f"sequential  : acc={float(accuracy(ball_seq, Xt, yt)) * 100:5.2f}%  "

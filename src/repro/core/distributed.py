@@ -1,19 +1,19 @@
 """Distributed one-pass StreamSVM — beyond-paper mesh parallelism.
 
 The stream is sharded into contiguous ranges across mesh axes; each shard
-runs Algorithm 1/2 locally (one pass, O(D) state), then shards exchange their
-balls with an all_gather and every shard deterministically folds them with the
-paper's Sec-4.3 merge operator (exact in the augmented space because shards
-touch disjoint slack coordinates — DESIGN.md §5).
+runs Algorithm 1/2 locally (one pass, O(B*D) state), then shards exchange
+their balls with an all_gather and every shard deterministically folds them
+with the paper's Sec-4.3 merge operator (exact in the augmented space
+because shards touch disjoint slack coordinates — DESIGN.md §5).
 
-Two entry points:
+Entry points:
 
-``fit_sharded``       one model, scan-path Algorithm 1/2 per shard.
 ``fit_bank_sharded``  a BANK of B models per shard via the tiled multi-ball
-                      Pallas engine — M stream shards x B models in ONE data
-                      pass each, folded with the bank-vectorized merge
-                      (meb.fold_merge over the gathered (S, B, ...) stack,
-                      outside the mesh program when called eagerly).
+                      Pallas engine (one model is B = 1) — M stream shards
+                      x B models in ONE data pass each, folded with the
+                      bank-vectorized merge (meb.fold_merge over the
+                      gathered (S, B, ...) stack, outside the mesh program
+                      when called eagerly).
                       Each shard reads its own live row count, so any N
                       works on any shard count.
 ``fit_kernel_bank_sharded``
@@ -45,7 +45,6 @@ from jax import shard_map as _shard_map
 
 from .kernel_bank import KernelBank, _fit_kernel_bank
 from .meb import Ball, fold_merge, merge_banks, merge_kernel_banks
-from .streamsvm import fit, fit_lookahead
 
 
 def _mesh_axes(axis: str | Tuple[str, ...]) -> Tuple[str, ...]:
@@ -80,61 +79,6 @@ def shard_ranges(n: int, n_shards: int) -> list[Tuple[int, int]]:
         (min(j * shard_n, n), min((j + 1) * shard_n, n))
         for j in range(n_shards)
     ]
-
-
-def fit_sharded(
-    X: jax.Array,
-    y: jax.Array,
-    c: float,
-    mesh: Mesh,
-    *,
-    axis: str | Tuple[str, ...] = "data",
-    lookahead: int = 1,
-    variant: str = "exact",
-) -> Ball:
-    """One-pass fit with the stream sharded over ``axis`` of ``mesh``.
-
-    X: (N, D), y: (N,). N must divide by the product of the axis sizes
-    (``fit_bank_sharded`` lifts this by padding with inert rows).
-    Returns the merged Ball, replicated on every device.
-    """
-    axes = _mesh_axes(axis)
-    n_shards = _n_shards(mesh, axes)
-    if X.shape[0] % n_shards != 0:
-        raise ValueError(
-            f"X rows must divide evenly over the {n_shards} stream shards of "
-            f"mesh axes {axes}: got X.shape={X.shape}. Pad the stream, or "
-            "use fit_bank_sharded, which pads ragged remainders with inert "
-            "sign-0 rows."
-        )
-
-    def local_fit(Xs, ys):
-        # Xs: (N/n_shards, D) local contiguous range of the stream.
-        if lookahead <= 1:
-            ball = fit(Xs, ys, c, variant=variant)
-        else:
-            ball = fit_lookahead(Xs, ys, c, lookahead, variant=variant)
-        # Exchange balls and fold identically on every shard.
-        stacked = Ball(
-            w=jax.lax.all_gather(ball.w, axes, tiled=False),
-            r=jax.lax.all_gather(ball.r, axes),
-            xi2=jax.lax.all_gather(ball.xi2, axes),
-            m=jax.lax.all_gather(ball.m, axes),
-        )
-        return fold_merge(stacked)
-
-    spec = P(axes)
-    fn = _shard_map(
-        local_fit,
-        mesh=mesh,
-        in_specs=(spec, spec),
-        out_specs=jax.tree.map(lambda _: P(), Ball(0, 0, 0, 0)),
-        # scalar ball carries are constant-initialized per shard
-        check_vma=False,
-    )
-    X = jax.device_put(X, NamedSharding(mesh, P(axes)))
-    y = jax.device_put(y, NamedSharding(mesh, P(axes)))
-    return fn(X, y)
 
 
 @partial(

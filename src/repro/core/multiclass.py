@@ -1,14 +1,12 @@
 """One-vs-rest multiclass StreamSVM and hyper-parameter-grid fitting.
 
 Classes and C-grid points are embarrassingly parallel *in math* but share the
-same stream, so the default path flattens them onto the model axis of the
-tiled multi-ball Pallas engine (kernels.ops.streamsvm_fit_many): every
+same stream, so both wrappers flatten them onto the model axis of the tiled
+bank engine (``fit_bank`` -> kernels.ops.streamsvm_fit_many): every
 (block_n, D) tile is read from HBM once and updates all B models — bank
 tiling (``b_tile``) keeps that true for hundreds of classes x a C-grid, and
-``lookahead > 1`` runs the fused in-kernel Algorithm 2. The pre-engine vmap'd
-lax.scan path is kept as ``engine="scan"``. On a mesh, the class/grid axis maps to the
-`model` axis (see launch/train.py --svm-head) while the stream itself shards
-over (pod, data) via distributed.fit_sharded.
+``lookahead > 1`` runs the fused in-kernel Algorithm 2. ``mesh=`` shards the
+stream over a device mesh (distributed.fit_bank_sharded).
 """
 from __future__ import annotations
 
@@ -19,11 +17,10 @@ import jax.numpy as jnp
 
 from .meb import Ball
 from .multiball import fit_bank
-from .streamsvm import fit, fit_lookahead
 
 
 def _cast_ball(ball: Ball, dtype) -> Ball:
-    """Match the scan path's output dtype (the kernel accumulates in f32)."""
+    """Cast the bank to the stream's dtype (the kernel accumulates in f32)."""
     return Ball(
         w=ball.w.astype(dtype), r=ball.r.astype(dtype),
         xi2=ball.xi2.astype(dtype), m=ball.m,
@@ -40,7 +37,7 @@ def ovr_signs(labels: jax.Array, n_classes: int, dtype=jnp.float32) -> jax.Array
 @partial(
     jax.jit,
     static_argnames=(
-        "n_classes", "lookahead", "variant", "engine", "b_tile", "stream_dtype",
+        "n_classes", "lookahead", "variant", "b_tile", "stream_dtype",
         "bank_resident", "mesh", "shard_axis",
     ),
 )
@@ -52,7 +49,6 @@ def fit_ovr(
     *,
     lookahead: int = 1,
     variant: str = "exact",
-    engine: str = "pallas",
     b_tile: int | None = None,
     stream_dtype=None,
     bank_resident: str = "auto",
@@ -61,53 +57,40 @@ def fit_ovr(
 ) -> Ball:
     """labels: (N,) int in [0, n_classes). Returns Ball stacked over classes.
 
-    ``c`` is traced (sweeping C reuses one compilation). The default engine
-    flattens all classes onto the bank axis of the tiled Pallas engine —
-    including ``lookahead > 1``, which runs the fused in-kernel Algorithm 2 —
-    so hundreds of classes train in ONE stream pass; ``b_tile`` bounds the
+    ``c`` is traced (sweeping C reuses one compilation). All classes flatten
+    onto the bank axis of the tiled Pallas engine — including
+    ``lookahead > 1``, which runs the fused in-kernel Algorithm 2 — so
+    hundreds of classes train in ONE stream pass; ``b_tile`` bounds the
     per-step VMEM working set, ``stream_dtype="bf16"`` halves stream HBM
     traffic, and ``bank_resident="hbm"`` lifts the VMEM cap on the bank
     (classes x C-grid banks beyond VMEM scratch double-buffer through HBM —
-    see kernels.ops). ``engine="scan"`` keeps the pre-engine vmap'd lax.scan path
-    (Badoiu-Clarkson window solves for lookahead > 1).
+    see kernels.ops).
 
-    ``mesh=`` (pallas engine only) shards the stream over ``shard_axis`` of
+    ``mesh=`` shards the stream over ``shard_axis`` of
     a device mesh and folds the per-shard banks with the Sec-4.3 merge:
     classes x shards in one pass of each shard's range (fit_bank_sharded).
     """
-    if engine not in ("pallas", "scan"):
-        raise ValueError(f"unknown engine {engine!r}; expected 'pallas' or 'scan'")
     if variant not in ("exact", "paper-listing"):
         raise ValueError(
             f"unknown variant {variant!r}; expected 'exact' or 'paper-listing'"
         )
-    if mesh is not None and engine != "pallas":
-        raise ValueError(
-            f"mesh= requires engine='pallas': got engine={engine!r}"
-        )
     ys = ovr_signs(labels, n_classes, X.dtype)
-    if engine == "pallas":
-        if lookahead <= 1:
-            bank = fit_bank(
-                X, ys, c, variant=variant, b_tile=b_tile,
-                stream_dtype=stream_dtype, bank_resident=bank_resident,
-                mesh=mesh, shard_axis=shard_axis,
-            )
-        else:
-            bank = fit_bank(
-                X, ys, c,
-                variant="lookahead" if variant == "exact" else "lookahead-paper",
-                lookahead=int(lookahead),
-                b_tile=b_tile, stream_dtype=stream_dtype,
-                bank_resident=bank_resident,
-                mesh=mesh, shard_axis=shard_axis,
-            )
-        return _cast_ball(bank, X.dtype)
     if lookahead <= 1:
-        f = lambda yv: fit(X, yv, c, variant=variant)
+        bank = fit_bank(
+            X, ys, c, variant=variant, b_tile=b_tile,
+            stream_dtype=stream_dtype, bank_resident=bank_resident,
+            mesh=mesh, shard_axis=shard_axis,
+        )
     else:
-        f = lambda yv: fit_lookahead(X, yv, c, lookahead, variant=variant, engine="qp")
-    return jax.vmap(f)(ys)
+        bank = fit_bank(
+            X, ys, c,
+            variant="lookahead" if variant == "exact" else "lookahead-paper",
+            lookahead=int(lookahead),
+            b_tile=b_tile, stream_dtype=stream_dtype,
+            bank_resident=bank_resident,
+            mesh=mesh, shard_axis=shard_axis,
+        )
+    return _cast_ball(bank, X.dtype)
 
 
 def predict_ovr(balls: Ball, X: jax.Array) -> jax.Array:
@@ -149,8 +132,8 @@ def predict_c_grid(balls: Ball, X: jax.Array, n_classes: int):
 @partial(
     jax.jit,
     static_argnames=(
-        "variant", "engine", "b_tile", "stream_dtype", "bank_resident",
-        "mesh", "shard_axis",
+        "variant", "b_tile", "stream_dtype", "bank_resident", "mesh",
+        "shard_axis",
     ),
 )
 def fit_c_grid(
@@ -159,7 +142,6 @@ def fit_c_grid(
     c_grid: jax.Array,
     *,
     variant: str = "exact",
-    engine: str = "pallas",
     b_tile: int | None = None,
     stream_dtype=None,
     bank_resident: str = "auto",
@@ -170,48 +152,17 @@ def fit_c_grid(
 
     Every grid point is a model in the engine's bank (c enters only through
     1/C, so the grid can be traced). Returns Ball stacked over the grid.
-    ``mesh=`` (pallas engine only) shards the stream over ``shard_axis`` and
-    folds the per-shard grid banks with the Sec-4.3 merge.
+    ``mesh=`` shards the stream over ``shard_axis`` and folds the per-shard
+    grid banks with the Sec-4.3 merge.
     """
-    if engine not in ("pallas", "scan"):
-        raise ValueError(f"unknown engine {engine!r}; expected 'pallas' or 'scan'")
-    if mesh is not None and engine != "pallas":
-        raise ValueError(
-            f"mesh= requires engine='pallas': got engine={engine!r}"
-        )
     c_grid = jnp.asarray(c_grid)
     b = c_grid.shape[0]
-    if engine == "pallas":
-        Y = jnp.broadcast_to(y[None, :], (b, y.shape[0])).astype(X.dtype)
-        return _cast_ball(
-            fit_bank(
-                X, Y, c_grid, variant=variant, b_tile=b_tile,
-                stream_dtype=stream_dtype, bank_resident=bank_resident,
-                mesh=mesh, shard_axis=shard_axis,
-            ),
-            X.dtype,
-        )
-
-    def f(cv):
-        from .meb import enclose_point, point_distance
-
-        c_inv = 1.0 / cv
-        xi2 = c_inv if variant == "exact" else jnp.asarray(1.0, X.dtype)
-        ball = Ball(
-            w=y[0] * X[0],
-            r=jnp.asarray(0.0, X.dtype),
-            xi2=jnp.asarray(xi2, X.dtype),
-            m=jnp.asarray(1, jnp.int32),
-        )
-        yx = y[1:, None] * X[1:]
-
-        def body(b_, row):
-            d = point_distance(b_, row, c_inv)
-            upd = d >= b_.r
-            new = enclose_point(b_, row, c_inv, variant=variant)
-            return jax.tree.map(lambda a_, o_: jnp.where(upd, a_, o_), new, b_), None
-
-        ball, _ = jax.lax.scan(body, ball, yx)
-        return ball
-
-    return jax.vmap(f)(c_grid)
+    Y = jnp.broadcast_to(y[None, :], (b, y.shape[0])).astype(X.dtype)
+    return _cast_ball(
+        fit_bank(
+            X, Y, c_grid, variant=variant, b_tile=b_tile,
+            stream_dtype=stream_dtype, bank_resident=bank_resident,
+            mesh=mesh, shard_axis=shard_axis,
+        ),
+        X.dtype,
+    )

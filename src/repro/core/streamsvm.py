@@ -2,8 +2,13 @@
 
 Entry points
 ------------
-fit(X, y, c)                    Algorithm 1 (closed-form updates), lax.scan.
-fit_lookahead(X, y, c, L)       Algorithm 2 (buffer L violators, BC solve).
+fit(X, y, c)                    Algorithm 1 (closed-form updates), lax.scan:
+                                the reference the bank engine is tested
+                                against (``fit_ball`` continues a ball).
+fit_lookahead(X, y, c, L)       Algorithm 2 for one model through the fused
+                                bank engine (kernels.ops.streamsvm_fit_many).
+fit_lookahead_ball(...)         Algorithm 2 as a lax.scan that solves each
+                                window of L violators (BC solve).
 fit_chunked(...)                python-level streaming driver over an
                                 iterator of chunks, with checkpoint hooks —
                                 the "real" one-pass entry point.
@@ -13,8 +18,8 @@ fit_chunked_many(...)           same driver for a BANK of B models (classes x
 decision_function / predict     linear classifier readout.
 
 All core math lives in meb.py / qp.py; this module provides the streaming
-control flow. Everything jits; fit/fit_lookahead vmap over classes and over
-hyper-parameter grids (see multiclass.py).
+control flow. Banks of classes x hyper-parameter grids train through
+``fit_bank`` (multiball.py, multiclass.py).
 """
 from __future__ import annotations
 
@@ -114,44 +119,37 @@ def fit_lookahead(
     c: float,
     lookahead: int,
     *,
-    qp_iters: int = 128,
     variant: str = "exact",
-    engine: str = "pallas",
     block_n: int = 256,
     stream_dtype=None,
     bank_resident: str = "auto",
 ) -> Ball:
-    """Algorithm 2. lookahead=1 ~ Algorithm 1 (exactly, for engine="pallas").
+    """Algorithm 2 for one model. lookahead=1 is Algorithm 1 exactly.
 
-    engine="pallas" (default) routes through the fused lookahead path of the
-    multi-ball engine: the L-row window lives in VMEM next to the ball and is
-    flushed farthest-point-first inside the kernel (greedy Badoiu-Clarkson
+    Runs the fused lookahead path of the bank engine as a bank of one: the
+    L-row window lives in VMEM next to the ball and is flushed
+    farthest-point-first inside the kernel (greedy Badoiu-Clarkson
     insertion over the window), so Algorithm 2 costs the same single stream
-    read as Algorithm 1. engine="qp" keeps the pre-engine behavior — a
-    lax.scan that solves the buffered window with the iterative BC solver in
-    qp.py (also what ``fit_chunked`` uses, chunk by chunk). The two accept
-    slightly different core-vector sets (greedy insertion vs window solve);
-    both satisfy the paper's enclosure guarantee.
+    read as Algorithm 1. ``fit_lookahead_ball`` (what ``fit_chunked`` uses,
+    chunk by chunk) solves each buffered window with the iterative BC
+    solver in qp.py instead; the two accept slightly different core-vector
+    sets (greedy insertion vs window solve), and both satisfy the paper's
+    enclosure guarantee.
     """
-    if engine not in ("pallas", "qp"):
-        raise ValueError(f"unknown engine {engine!r}; expected 'pallas' or 'qp'")
     if variant not in ("exact", "paper-listing"):
         raise ValueError(
             f"unknown variant {variant!r}; expected 'exact' or 'paper-listing'"
         )
-    if engine == "pallas":
-        from .multiball import fit_bank
+    from .multiball import fit_bank
 
-        bank = fit_bank(
-            X, y[None, :].astype(X.dtype), c,
-            variant="lookahead" if variant == "exact" else "lookahead-paper",
-            lookahead=int(lookahead),
-            block_n=block_n, stream_dtype=stream_dtype,
-            bank_resident=bank_resident,
-        )
-        return jax.tree.map(lambda v: v[0], bank)
-    ball = init_ball(X[0], y[0], c, variant=variant)
-    return fit_lookahead_ball(ball, X[1:], y[1:], c, lookahead, qp_iters=qp_iters)
+    bank = fit_bank(
+        X, y[None, :].astype(X.dtype), c,
+        variant="lookahead" if variant == "exact" else "lookahead-paper",
+        lookahead=int(lookahead),
+        block_n=block_n, stream_dtype=stream_dtype,
+        bank_resident=bank_resident,
+    )
+    return jax.tree.map(lambda v: v[0], bank)
 
 
 # ---------------------------------------------------------------------------

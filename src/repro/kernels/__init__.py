@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
-streamsvm_scan — blocked one-pass Algorithm 1: single-ball, and the tiled
-                 multi-ball bank engine — a 2-D data-major grid training B
-                 models per stream pass for arbitrary B, with fused
+streamsvm_scan — the one-pass training engine: Algorithm 1 for a bank of
+                 B models (one model is B = 1) on a 2-D data-major grid,
+                 one stream pass for arbitrary B, with fused
                  Algorithm-2 lookahead windows, a bf16 stream-tile policy,
                  and two bank residencies sharing one compute core: VMEM
                  scratch, or HBM/ANY double-buffered through a 2-slot
@@ -22,7 +22,6 @@ from .ops import (
     gram,
     predict_bank,
     predict_kernel_bank,
-    streamsvm_fit,
     streamsvm_fit_many,
 )
 
@@ -30,6 +29,5 @@ __all__ = [
     "gram",
     "predict_bank",
     "predict_kernel_bank",
-    "streamsvm_fit",
     "streamsvm_fit_many",
 ]

@@ -1,11 +1,14 @@
 """jit'd public wrappers around the Pallas kernels (padding, dtype policy).
 
-These are the entry points the rest of the framework uses; they handle
-128-alignment padding (the lanes of a stream whose D is not a multiple of
-128; otherwise the bank engine reads its stream and signs in place),
-interpret-mode selection (``resolve_interpret``: the one place it is
-decided), bank tiling (`b_tile`), the stream dtype policy, and state
-packing. Semantics match ref.py exactly (tests sweep shapes and dtypes).
+These are the entry points the rest of the framework uses: one training
+engine (``streamsvm_fit_many``, a bank of B models; one model is B = 1),
+the serving readouts (``predict_bank``, ``predict_kernel_bank``) and the
+Gram (``gram``). They handle 128-alignment padding (the lanes of a stream
+whose D is not a multiple of 128; otherwise the bank engine reads its
+stream and signs in place), interpret-mode selection
+(``resolve_interpret``: the one place it is decided), bank tiling
+(`b_tile`), the stream dtype policy, and state packing. Semantics match
+ref.py exactly (tests sweep shapes and dtypes).
 
 Dtype policy
 ------------
@@ -46,8 +49,7 @@ and lookahead windows) while the grid runs:
 Configs that fit NO residency (e.g. a single (b_tile, D) ring slot already
 beyond the budget) are rejected up front with a ValueError carrying the
 byte breakdown — including when ``bank_resident="vmem"`` is forced on an
-oversized bank, which previously died deep inside Pallas lowering with an
-opaque scratch-allocation error.
+oversized bank.
 """
 from __future__ import annotations
 
@@ -64,7 +66,6 @@ from .streamsvm_scan import (
     D_CHUNK,
     _rows_per_step,
     streamsvm_scan_many_pallas,
-    streamsvm_scan_pallas,
 )
 
 _STREAM_DTYPES = {
@@ -118,8 +119,7 @@ def bank_tiling(b: int, b_tile: int | None):
     the engines stage is then a sublane slab at an 8-aligned row offset,
     which Mosaic takes for any such size; the predict engine's outputs are
     laid out so their tiles are legal too (see ``predict_bank_pallas``).
-    The single source of truth for this policy — the throughput harnesses
-    derive their modeled tile counts from here too.
+    The single source of truth for this policy.
     """
     bt = -(-b // 8) * 8 if b_tile is None else -(-b_tile // 8) * 8
     return bt, -(-b // bt)
@@ -129,11 +129,9 @@ def gram_tiling(m: int, n: int, bm: int, bn: int):
     """Resolve the Gram kernel's derived (bm_, bn_) block shapes.
 
     Shrinks the requested tiles to the data but keeps the f32 sublane/lane
-    alignment Mosaic requires — bm_ a multiple of 8, bn_ a multiple of 128.
-    (The old ``min(bm, max(8, m))`` produced misaligned blocks for odd M/N,
-    e.g. m=100 -> bm_=100, which only survived in interpret mode.) The
-    single source of truth for this policy; regression-tested on odd shapes
-    in tests/test_kernel_bank.py.
+    alignment Mosaic requires — bm_ a multiple of 8, bn_ a multiple of 128,
+    for any M and N. The single source of truth for this policy;
+    regression-tested on odd shapes in tests/test_kernel_bank.py.
     """
     bm_ = -(-min(bm, max(8, m)) // 8) * 8
     bn_ = -(-min(bn, max(128, n)) // 128) * 128
@@ -151,8 +149,7 @@ def ovr_group_tiling(b: int, n_classes: int, b_tile: int | None):
     group), and the group count padded to a whole number of tiles. Any
     g_tile compiles: the kernel writes each tile's (q_block, g_tile) result
     into an output plane of its own. The single source of truth for this
-    policy — the serving throughput harness derives its modeled tile counts
-    from here too.
+    policy.
     """
     g = b // n_classes
     nc_pad = -(-n_classes // 8) * 8
@@ -527,46 +524,6 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-@partial(jax.jit, static_argnames=("block_n", "interpret"))
-def streamsvm_fit(
-    X: jax.Array,
-    y: jax.Array,
-    c,
-    ball: Ball | None = None,
-    *,
-    block_n: int = 256,
-    interpret: bool | None = None,
-) -> Ball:
-    """One-pass Algorithm 1 via the Pallas kernel. Returns a core Ball.
-
-    Starts from `ball` if given, else initializes from the first example
-    (exact variant: xi2 = 1/C). ``c`` is traced (a C sweep reuses one
-    compilation); only ``block_n``/``interpret`` are static.
-    """
-    n, d = X.shape
-    if y.shape != (n,):
-        raise ValueError(
-            f"y must be (N,) labels matching X: got y.shape={y.shape}, "
-            f"X.shape={X.shape}"
-        )
-    c_inv = 1.0 / jnp.asarray(c, jnp.float32)
-    if ball is None:
-        w0 = y[0] * X[0]
-        r0, xi20, m0 = jnp.float32(0.0), c_inv, 1
-        X, y = X[1:], y[1:]
-        n -= 1
-    else:
-        w0, r0, xi20, m0 = ball.w, ball.r, ball.xi2, ball.m
-    Xp = _pad_to(_pad_to(X.astype(jnp.float32), 128, 1), block_n, 0)
-    yp = _pad_to(y.astype(jnp.float32), block_n, 0)
-    w0p = _pad_to(w0.astype(jnp.float32), 128, 0)
-    w, r, xi2, m = streamsvm_scan_pallas(
-        Xp, yp, w0p, r0, xi20, c_inv, m0,
-        n_valid=n, block_n=block_n, interpret=resolve_interpret(interpret),
-    )
-    return Ball(w=w[:d], r=r, xi2=xi2, m=m)
-
-
 @partial(
     jax.jit,
     static_argnames=(
@@ -640,6 +597,11 @@ def streamsvm_fit_many(
     a ValueError carrying the per-step byte breakdown and the budget
     (``vmem_budget_bytes`` / REPRO_VMEM_BUDGET_BYTES).
     """
+    if Y.ndim != 2:
+        raise ValueError(
+            f"Y must be 2-D (B, N) sign rows: got Y.shape={Y.shape}, "
+            f"X.shape={X.shape}; one model's labels y are Y = y[None]"
+        )
     b, n_y = Y.shape
     n, d = X.shape
     if n_y != n:

@@ -1,42 +1,29 @@
-"""Pallas TPU kernel: one-pass StreamSVM over a VMEM-blocked stream.
+"""Pallas TPU kernel: the one-pass StreamSVM bank engine.
 
-TPU adaptation of Algorithm 1 (DESIGN.md §3). The ball state (w, R, xi2, M)
-lives in VMEM/SMEM scratch across a *sequential* grid over row-blocks of the
-stream; each grid step:
+TPU adaptation of Algorithm 1 (DESIGN.md §3) for a BANK of B independent
+models (``_kernel_many`` / ``streamsvm_scan_many_pallas``; a single model
+is a bank of one). The grid is 2-D, ``(n_block, bank_tile)``, in DATA-MAJOR
+order: the data-block axis is outer and the bank-tile axis inner, so each
+(block_n, D) stream tile is fetched from HBM exactly once (its BlockSpec
+index ignores the bank axis, so Pallas elides the re-copy across the inner
+iterations) and is revisited by every (b_tile, D) tile of the bank. Per
+(i, j) step:
 
-  1. loads a (block_n, D) tile of stream rows from HBM into VMEM,
-  2. computes the block Gram matrix G = YX YX^T and the state inner products
-     g_j = <w, yx_j> on the MXU (one matmul + one matvec per block instead of
-     the paper's per-row scalar loop),
-  3. runs the inherently-sequential conditional updates with an in-register
-     fori_loop over the block's rows, maintaining <w, yx_k> for k > j with
-     rank-1 corrections from G (O(block_n) per row) and updating w itself
-     with a single AXPY per *accepted* row. The bank engine's loop (below)
-     carries the next rows' g_k forward, so the chain from one row to the
-     next holds no cross-lane sum.
+  1. the unsigned block Gram G = X X^T (and Algorithm 1's band of it)
+     depends on the block alone: the block's first step (j = 0) fills it
+     into scratch on the MXU and the other bank tiles of the block read it
+     there; the tile's inner products g = <w, x_k> are one matmul;
+  2. a fori_loop over the block's rows runs the inherently-sequential
+     conditional updates, vectorized across the b_tile models (one model
+     per sublane row; per-model label signs re-applied as rank-1 factors),
+     maintaining g_k for k > j with rank-1 corrections from G (O(block_n)
+     per row);
+  3. the bank tile is updated once per block via accumulated (decay,
+     alpha) coefficients — a single (b_tile, block_n) x (block_n, D)
+     matmul.
 
-Per-block cost: one (block_n x D x block_n) matmul + block_n * O(block_n + D)
-vector work — MXU-friendly, and exactly equal in result to the reference
-scan (tests sweep shapes/dtypes against ref.py). The bank engine below pays
-that Gram once per data block, not once per bank tile.
-
-Scalar state is carried in an SMEM (4,)-vector: [r, xi2, |w|^2, m].
-
-The multi-ball variant (``_kernel_many`` / ``streamsvm_scan_many_pallas``)
-is the same pass generalized to a BANK of B independent models on a 2-D grid
-``(n_block, bank_tile)`` with DATA-MAJOR iteration order: the data-block axis
-is outer and the bank-tile axis inner, so each (block_n, D) stream tile is
-fetched from HBM exactly once (its BlockSpec index ignores the bank axis, so
-Pallas elides the re-copy across the inner iterations) and is revisited by
-every (b_tile, D) tile of the bank. The unsigned block Gram (and Algorithm
-1's band of it) depends on the block alone: the block's first step (j = 0)
-fills it into scratch and the other bank tiles of the block read it there.
-Per (i, j) step: one tile/block matmul and that shared Gram feed a
-fori_loop whose conditional update is vectorized across the b_tile models
-(one model per sublane row; per-model label signs re-applied as rank-1
-factors), and the bank tile is updated once per block via accumulated
-(decay, alpha) coefficients — a single (b_tile, block_n) x (block_n, D)
-matmul. B models still cost ONE pass of data movement, for arbitrary B.
+B models cost ONE pass of data movement, for arbitrary B, and the result
+equals the reference scan's (tests sweep shapes/dtypes against ref.py).
 
 Mosaic lowers no dynamic slice of a value, so the row loop never indexes a
 value at the traced row: the Gram row is a sublane read of a VMEM scratch
@@ -47,7 +34,7 @@ of any multiple of 8 models is one aligned slab in VMEM and one aligned DMA.
 
 A lane sum is a round trip through the cross-lane unit, about 100 cycles
 on a v5e, against a chain of about 50 per row (d^2, an exact sqrt and
-divide, s). So the bank's Algorithm-1 loop keeps those sums off the chain:
+divide, s). So the Algorithm-1 loop keeps those sums off the chain:
 it runs ``_rows_per_step`` rows a step, lane-sums the NEXT step's columns
 of g and y at the start of a step, and brings them up to date row by row
 in (b_tile, 128) arithmetic with the very operations the full-width update
@@ -155,91 +142,6 @@ def _rows_per_step(block_n: int) -> int:
     for a one-tile bank of 24 models and for tiles of 256 (PERF.md).
     """
     return math.gcd(2, block_n)
-
-
-def _kernel(
-    x_ref,  # (block_n, D) VMEM tile of X
-    y_ref,  # (block_n, 1) VMEM tile of labels
-    w0_ref,  # (1, D) initial weight vector
-    s0_ref,  # (1, 4) initial scalars [r, xi2, c_inv, m]
-    nv_ref,  # (1, 1) number of valid rows (N before padding)
-    w_out_ref,  # (1, D) output weights
-    s_out_ref,  # (1, 4) output scalars
-    w_ref,  # VMEM scratch (1, D) — persistent ball center
-    st_ref,  # SMEM scratch (4,) — persistent [r, xi2, wsq, m]
-    gram_ref,  # VMEM scratch (block_n, block_n) — this block's Gram
-    *,
-    block_n: int,
-):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        w_ref[...] = w0_ref[...]
-        st_ref[0] = s0_ref[0, 0]  # r
-        st_ref[1] = s0_ref[0, 1]  # xi2
-        st_ref[2] = jnp.sum(w0_ref[...] * w0_ref[...])  # |w|^2
-        st_ref[3] = s0_ref[0, 3]  # m (as float)
-
-    c_inv = s0_ref[0, 2]
-    n_valid = nv_ref[0, 0]
-
-    yx = x_ref[...] * y_ref[...]  # (block_n, D) label-signed rows
-    d = yx.shape[1]
-    rows = lambda c: yx[:, c]
-    # Block Gram and state inner products — MXU work.
-    gram_ref[...] = _dot_nt(rows, rows, d)  # (block_n, block_n)
-    g0 = _dot_nt(lambda c: w_ref[:, c], rows, d)  # (1, block_n)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
-    row_base = step * block_n
-
-    def body(j, carry):
-        g, w, r, xi2, wsq, m = carry
-        # Row j is read by sublane from the refs and by a one-hot lane sum
-        # from the values (exact: every other term is 0.0). Rows past
-        # n_valid AND rows with label sign 0 are inert: sign-0 rows are the
-        # stream-padding contract, distinct from a genuine zero FEATURE row,
-        # which is a legitimate slack-only point.
-        hit = lane == j
-        grow = gram_ref[pl.ds(j, 1), :]  # (1, block_n) Gram row j
-        gjj = jnp.sum(jnp.where(hit, grow, 0.0))
-        gj = jnp.sum(jnp.where(hit, g, 0.0))
-        yj = jnp.sum(y_ref[pl.ds(j, 1), :])
-        yxj = x_ref[pl.ds(j, 1), :] * y_ref[pl.ds(j, 1), :]  # (1, D)
-        # d^2 = |w|^2 - 2 g_j + G_jj + xi2 + 1/C  (current w)
-        d2 = wsq - 2.0 * gj + gjj + xi2 + c_inv
-        d = jnp.sqrt(jnp.maximum(d2, 1e-12))
-        upd = jnp.logical_and(
-            jnp.logical_and(d >= r, row_base + j < n_valid), yj != 0.0
-        )
-        s = jnp.where(upd, 0.5 * (1.0 - r / d), 0.0)
-        # rank-1 maintenance of g_k = <w, yx_k> after w <- (1-s) w + s yx_j
-        g = (1.0 - s) * g + s * grow
-        w = (1.0 - s) * w + s * yxj
-        wsq = (1.0 - s) ** 2 * wsq + 2.0 * s * (1.0 - s) * gj + s**2 * gjj
-        r = jnp.where(upd, r + 0.5 * (d - r), r)
-        xi2 = xi2 * (1.0 - s) ** 2 + s**2 * c_inv
-        m = m + jnp.where(upd, 1.0, 0.0)
-        return g, w, r, xi2, wsq, m
-
-    g, w, r, xi2, wsq, m = jax.lax.fori_loop(
-        0,
-        block_n,
-        body,
-        (g0, w_ref[...], st_ref[0], st_ref[1], st_ref[2], st_ref[3]),
-    )
-    w_ref[...] = w
-    st_ref[0], st_ref[1], st_ref[2], st_ref[3] = r, xi2, wsq, m
-
-    @pl.when(step == pl.num_programs(0) - 1)
-    def _finish():
-        w_out_ref[...] = w_ref[...]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 4), 1)
-        s_out_ref[...] = jnp.where(
-            lane == 0, st_ref[0],
-            jnp.where(lane == 1, st_ref[1],
-                      jnp.where(lane == 2, c_inv, st_ref[3])),
-        )  # a vector store: VMEM takes no scalar stores
 
 
 # Per-model state arrays are (rows, 128) slabs: one model per sublane row,
@@ -779,66 +681,6 @@ def _kernel_many(
         @pl.when(t == T - 1)
         def _drain_last():
             wait_out(t)
-
-
-def streamsvm_scan_pallas(
-    X: jax.Array,
-    y: jax.Array,
-    w0: jax.Array,
-    r0,
-    xi20,
-    c_inv,
-    m0,
-    *,
-    n_valid: int | None = None,
-    block_n: int = 256,
-    interpret: bool = False,
-):
-    """Run Algorithm 1 from (w0, r0, xi20, m0) over the padded stream (X, y).
-
-    X: (N, D) float32 — D should be padded to a multiple of 128 by ops.py,
-    N to a multiple of block_n; rows >= n_valid and rows with y == 0 are
-    ignored. Returns (w, r, xi2, m).
-    """
-    n, d = X.shape
-    if n % block_n != 0:
-        raise ValueError(
-            f"N={n} must be a multiple of block_n={block_n} (pad the stream; "
-            "ops.streamsvm_fit does this)"
-        )
-    grid = (n // block_n,)
-
-    w0 = w0.reshape(1, d).astype(jnp.float32)
-    s0 = jnp.array([[r0, xi20, c_inv, m0]], jnp.float32)
-    nv = jnp.array([[n if n_valid is None else n_valid]], jnp.int32)
-
-    w_out, s_out = pl.pallas_call(
-        functools.partial(_kernel, block_n=block_n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, 4), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, 4), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, 4), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.SMEM((4,), jnp.float32),
-            pltpu.VMEM((block_n, block_n), jnp.float32),
-        ],
-        interpret=interpret,
-        name="streamsvm_scan",
-    )(X.astype(jnp.float32), y.reshape(n, 1).astype(jnp.float32), w0, s0, nv)
-    return w_out[0], s_out[0, 0], s_out[0, 1], s_out[0, 3].astype(jnp.int32)
 
 
 def streamsvm_scan_many_pallas(

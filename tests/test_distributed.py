@@ -11,7 +11,7 @@ _SCRIPT = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import fit, fit_sharded, accuracy
+from repro.core import accuracy, bank_take, fit, fit_bank
 from repro.launch.mesh import make_host_mesh
 from repro.optim.grad_compress import (
     ef_init, compress_grads_topk, int8_quant, int8_dequant,
@@ -30,7 +30,7 @@ X = rng.normal(size=(N, D)).astype(np.float32)
 y = np.sign(rng.normal(size=N) + 2 * X[:, 0]).astype(np.float32); y[y == 0] = 1
 X /= np.linalg.norm(X, axis=1, keepdims=True)  # K(x,x)=1 assumption
 mesh = jax.make_mesh((8,), ("data",))
-bs = fit_sharded(jnp.asarray(X), jnp.asarray(y), 10.0, mesh)
+bs = bank_take(fit_bank(jnp.asarray(X), jnp.asarray(y)[None], 10.0, mesh=mesh), 0)
 bq = fit(jnp.asarray(X), jnp.asarray(y), 10.0)
 acc_s = float(accuracy(bs, jnp.asarray(X), jnp.asarray(y)))
 acc_q = float(accuracy(bq, jnp.asarray(X), jnp.asarray(y)))

@@ -133,8 +133,6 @@ def test_chunked_many_grid_resume_equals_full_grid():
 
 def test_chunked_many_ovr_sign_rows():
     """(B, n) per-model sign chunks (one-vs-rest) stream correctly."""
-    from repro.kernels import streamsvm_fit
-
     rng = np.random.default_rng(5)
     X = rng.normal(size=(500, 8)).astype(np.float32)
     labels = rng.integers(0, 4, size=500)
@@ -147,7 +145,7 @@ def test_chunked_many_ovr_sign_rows():
 
     out = fit_chunked_many(chunks(), cs)
     for k in range(4):
-        single = streamsvm_fit(jnp.asarray(X), jnp.asarray(Y[k]), 10.0)
+        single = fit(jnp.asarray(X), jnp.asarray(Y[k]), 10.0)
         np.testing.assert_allclose(
             np.asarray(out.ball.w[k]), np.asarray(single.w), rtol=2e-4, atol=2e-5
         )

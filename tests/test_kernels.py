@@ -1,10 +1,14 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp ref."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp ref.
+
+One model trains through the bank engine as a bank of one (Y = y[None]).
+"""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import fit
-from repro.kernels import gram, streamsvm_fit
+from repro.core import bank_take, fit, fit_ball, fit_bank
+from repro.kernels import gram
 from repro.kernels.ref import gram_ref, streamsvm_scan_ref
 
 
@@ -18,7 +22,7 @@ def test_streamsvm_kernel_vs_ref(n, d, block_n):
     rng = np.random.default_rng(n + d)
     X = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     y = jnp.asarray(np.sign(rng.normal(size=n)).astype(np.float32))
-    ball = streamsvm_fit(X, y, 7.0, block_n=block_n)
+    ball = bank_take(fit_bank(X, y[None], 7.0, block_n=block_n), 0)
     w, r, xi2, m = streamsvm_scan_ref(
         X[1:], y[1:], y[0] * X[0], 0.0, 1.0 / 7.0, 1.0 / 7.0, 1
     )
@@ -32,7 +36,7 @@ def test_streamsvm_kernel_equals_core_fit():
     rng = np.random.default_rng(0)
     X = jnp.asarray(rng.normal(size=(777, 90)).astype(np.float32))
     y = jnp.asarray(np.sign(rng.normal(size=777)).astype(np.float32))
-    bk = streamsvm_fit(X, y, 3.0)
+    bk = bank_take(fit_bank(X, y[None], 3.0), 0)
     bc = fit(X, y, 3.0)
     np.testing.assert_allclose(np.asarray(bk.w), np.asarray(bc.w), rtol=2e-4, atol=2e-5)
     assert int(bk.m) == int(bc.m)
@@ -51,13 +55,15 @@ def test_gram_kernel_vs_ref(m, n, d, epilogue, dtype):
 
 
 def test_streamsvm_kernel_continues_from_ball():
-    """Kernel restart mid-stream == one continuous pass."""
+    """Kernel restart mid-stream from a reference ball == the reference's
+    one continuous pass."""
     rng = np.random.default_rng(5)
     X = jnp.asarray(rng.normal(size=(512, 64)).astype(np.float32))
     y = jnp.asarray(np.sign(rng.normal(size=512)).astype(np.float32))
-    b_half = streamsvm_fit(X[:256], y[:256], 5.0)
-    b_rest = streamsvm_fit(X[256:], y[256:], 5.0, ball=b_half)
-    b_full = streamsvm_fit(X, y, 5.0)
+    b_half = fit(X[:256], y[:256], 5.0)
+    half = jax.tree.map(lambda v: jnp.asarray(v)[None], b_half)
+    b_rest = bank_take(fit_bank(X[256:], y[None, 256:], 5.0, half), 0)
+    b_full = fit_ball(b_half, X[256:], y[256:], 5.0)
     np.testing.assert_allclose(np.asarray(b_rest.w), np.asarray(b_full.w), rtol=2e-4, atol=2e-5)
     assert int(b_rest.m) == int(b_full.m)
 

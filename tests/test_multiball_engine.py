@@ -1,16 +1,17 @@
 """Multi-ball engine (streamsvm_fit_many): one data pass, B models.
 
-Parity sweeps against (a) a loop of single-ball Pallas fits and (b) the
-pure-jnp bank reference, across (B, N, D, block_n) including unaligned
-shapes; bank checkpoint/restart; engine-backed fit_ovr / fit_c_grid vs their
-pre-engine scan paths.
+Parity sweeps against (a) the same engine fitting each model alone, as a
+bank of one, and (b) the pure-jnp bank reference, across (B, N, D, block_n)
+including unaligned shapes; bank checkpoint/restart; fit_ovr / fit_c_grid
+against the lax.scan reference (core.fit) vmapped over their models.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import fit_bank, fit_c_grid, fit_ovr
-from repro.kernels import streamsvm_fit, streamsvm_fit_many
+from repro.core import fit, fit_ball, fit_bank, fit_c_grid, fit_ovr, ovr_signs
+from repro.kernels import streamsvm_fit_many
 from repro.kernels.ref import streamsvm_scan_many_ref
 
 
@@ -34,7 +35,8 @@ def test_fit_many_matches_per_ball_loop(b, n, d, block_n):
     bank = streamsvm_fit_many(X, Y, cs, block_n=block_n)
     assert bank.w.shape == (b, d)
     for i in range(b):
-        single = streamsvm_fit(X, Y[i], float(cs[i]), block_n=block_n)
+        alone = streamsvm_fit_many(X, Y[i : i + 1], cs[i : i + 1], block_n=block_n)
+        single = jax.tree.map(lambda v: v[0], alone)
         np.testing.assert_allclose(
             np.asarray(bank.w[i]), np.asarray(single.w), rtol=2e-4, atol=2e-5
         )
@@ -98,7 +100,7 @@ def test_fit_ovr_engine_matches_scan_path():
     X = jnp.asarray(rng.normal(size=(600, 12)).astype(np.float32))
     labels = jnp.asarray(rng.integers(0, 8, size=600))
     be = fit_ovr(X, labels, 8, 10.0)
-    bs = fit_ovr(X, labels, 8, 10.0, engine="scan")
+    bs = jax.vmap(lambda yv: fit(X, yv, 10.0))(ovr_signs(labels, 8))
     np.testing.assert_allclose(np.asarray(be.w), np.asarray(bs.w), rtol=2e-4, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(be.m), np.asarray(bs.m))
 
@@ -109,7 +111,7 @@ def test_fit_c_grid_engine_matches_scan_path():
     y = jnp.asarray(np.sign(rng.normal(size=700) + X[:, 0]))
     grid = jnp.asarray([0.5, 1.0, 10.0, 100.0, 1000.0])
     ge = fit_c_grid(X, y, grid)
-    gs = fit_c_grid(X, y, grid, engine="scan")
+    gs = jax.vmap(lambda c: fit(X, y, c))(grid)
     np.testing.assert_allclose(np.asarray(ge.w), np.asarray(gs.w), rtol=2e-4, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(ge.m), np.asarray(gs.m))
 
@@ -123,10 +125,10 @@ def test_fit_bank_continues_from_single_model_states():
     X = jnp.asarray(rng.normal(size=(400, 16)).astype(np.float32))
     Y = jnp.asarray(np.sign(rng.normal(size=(8, 400))).astype(np.float32))
     cs = jnp.asarray([0.3, 1.0, 3.0, 10.0, 30.0, 100.0, 300.0, 1000.0])
-    singles = [streamsvm_fit(X[:150], Y[i, :150], float(cs[i])) for i in range(8)]
+    singles = [fit(X[:150], Y[i, :150], float(cs[i])) for i in range(8)]
     bank = fit_bank(X[150:], Y[:, 150:], cs, bank_stack(singles))
     for i in range(8):
-        cont = streamsvm_fit(X[150:], Y[i, 150:], float(cs[i]), ball=singles[i])
+        cont = fit_ball(singles[i], X[150:], Y[i, 150:], float(cs[i]))
         np.testing.assert_allclose(
             np.asarray(bank.w[i]), np.asarray(cont.w), rtol=2e-4, atol=2e-5
         )

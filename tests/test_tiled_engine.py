@@ -11,9 +11,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import fit_bank, fit_lookahead, fit_ovr, predict_ovr
+from repro.core import (
+    fit_bank, fit_lookahead, fit_lookahead_ball, fit_ovr, init_ball, predict_ovr,
+)
 from repro.core.distributed import fit_bank_sharded
-from repro.kernels import streamsvm_fit, streamsvm_fit_many
+from repro.kernels import streamsvm_fit_many
 from repro.kernels.ops import bank_engine_grid
 from repro.kernels.ref import (
     streamsvm_scan_lookahead_many_ref,
@@ -354,8 +356,8 @@ def test_lookahead_chunk_boundary_flush_semantics():
 
 
 def test_fit_lookahead_routes_to_engine():
-    """core.fit_lookahead default engine is the fused kernel; single model
-    must match the single-model oracle."""
+    """core.fit_lookahead runs the fused kernel; one model must match the
+    single-model oracle."""
     rng = np.random.default_rng(21)
     X = jnp.asarray(rng.normal(size=(400, 14)).astype(np.float32))
     y = jnp.asarray(np.sign(rng.normal(size=400)).astype(np.float32))
@@ -366,8 +368,8 @@ def test_fit_lookahead_routes_to_engine():
     )
     np.testing.assert_allclose(np.asarray(ball.w), w, rtol=2e-4, atol=2e-5)
     assert int(ball.m) == int(m)
-    # the BC window-solve path stays available
-    qp = fit_lookahead(X, y, 10.0, 8, engine="qp")
+    # the BC window-solve reference stays available
+    qp = fit_lookahead_ball(init_ball(X[0], y[0], 10.0), X[1:], y[1:], 10.0, 8)
     assert qp.w.shape == ball.w.shape
 
 
@@ -429,12 +431,6 @@ def test_bf16_lookahead_runs():
 
 def test_no_recompile_across_c_values():
     X, Y, _ = _bank_data(4, 96, 9, seed=41)
-    y = Y[0]
-    start = streamsvm_fit._cache_size()
-    for c in (0.5, 3.0, 77.0):
-        streamsvm_fit(X, y, c, block_n=32)
-    assert streamsvm_fit._cache_size() == start + 1  # one entry, three Cs
-
     start = streamsvm_fit_many._cache_size()
     for scale in (1.0, 2.0, 10.0):
         streamsvm_fit_many(X, Y, scale * jnp.ones((4,), jnp.float32), block_n=32)
@@ -452,8 +448,8 @@ def test_shape_errors_are_value_errors():
         streamsvm_fit_many(X[:32], Y, cs)  # Y rows don't match N
     with pytest.raises(ValueError, match="sign rows"):
         streamsvm_fit_many(X, Y.T, cs)
-    with pytest.raises(ValueError, match=r"y must be \(N,\)"):
-        streamsvm_fit(X, Y, 1.0)  # 2-D labels: classic fit_ovr misuse
+    with pytest.raises(ValueError, match=r"2-D.*\(64,\).*\(64, 8\)"):
+        fit_bank(X, Y[0], 1.0)  # one model's labels passed as Y, not y[None]
     with pytest.raises(ValueError, match="variant"):
         streamsvm_fit_many(X, Y, cs, variant="bogus")
     with pytest.raises(ValueError, match="lookahead"):

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import gram, predict_bank, streamsvm_fit, streamsvm_fit_many
+from repro.kernels import gram, predict_bank, streamsvm_fit_many
 
 D, B, N, Q = 784, 600, 4096, 512  # B = 200 classes x 3 C values
 
@@ -68,6 +68,7 @@ ENGINE = {
     "auto-quickstart-n65536": dict(shape=(65536, 784, 600)),
     "hbm-beyond-vmem-n16384": dict(bank_resident="hbm",
                                    shape=(16384, 4096, 3000)),
+    "one-model": dict(shape=(N, D, 1)),
 }
 
 
@@ -78,14 +79,6 @@ def test_bank_engine_compiles_for_v5e(one_chip, case):
     text = _compiled_text(
         lambda X, Y, cs: streamsvm_fit_many(X, Y, cs, interpret=False, **kw),
         one_chip, (n, d), (b, n), (b,),
-    )
-    assert "tpu_custom_call" in text
-
-
-def test_single_ball_engine_compiles_for_v5e(one_chip):
-    text = _compiled_text(
-        lambda X, y: streamsvm_fit(X, y, 1.0, interpret=False),
-        one_chip, (N, D), (N,),
     )
     assert "tpu_custom_call" in text
 
