@@ -38,7 +38,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map as _shard_map
@@ -408,6 +407,26 @@ def _place_rows(A, sharding: NamedSharding, axis: int, size: int) -> jax.Array:
     return jax.make_array_from_single_device_arrays(shape, sharding, parts)
 
 
+def _fold_device(mesh: Mesh) -> jax.Device:
+    """The device an eager fit folds on: the default device where the mesh
+    holds it, else the mesh's first."""
+    devices = list(mesh.devices.flat)
+    default = jax.config.jax_default_device
+    if not isinstance(default, jax.Device):
+        default = jax.local_devices()[0]
+    return default if default in devices else devices[0]
+
+
+def _replica_on(v: jax.Array, dev: jax.Device) -> jax.Array:
+    """The replicated array ``v``'s copy on ``dev`` as a one-device array:
+    the replica ``dev`` already holds, read in place, else a copy put
+    there."""
+    for shard in v.addressable_shards:
+        if shard.device == dev:
+            return shard.data
+    return jax.device_put(v, dev)
+
+
 def fit_bank_sharded(
     X: jax.Array,
     Y: jax.Array,
@@ -435,7 +454,8 @@ def fit_bank_sharded(
     per-shard (B, D) banks are gathered, and every model lane is folded
     with the Sec-4.3 merge (``meb.fold_merge`` over the (S, B, ...) stack).
     Called eagerly, the fold runs on one device, outside the mesh program,
-    so the result is bit-identical to single-device fits of the same ranges
+    over the gathered bits as the mesh program left them on that device, so
+    the result is bit-identical to single-device fits of the same ranges
     folded in order (``shard_ranges``) on any backend. Total data movement:
     each stream row is read from HBM exactly once, on exactly one shard.
 
@@ -463,8 +483,14 @@ def fit_bank_sharded(
       ``tile_visits`` count a shard's data blocks, where the engine fills
       the block Gram, and its (block, bank tile) visits
       (``kernels.ops.bank_engine_grid``);
-    - ``fit.gather``: the stacked banks to the host;
-    - ``fit.fold``: ``fold_merge`` of the live shards on one device;
+    - ``fit.gather``: the fold device's replica of the stacked banks (the
+      default device where the mesh holds it, else the mesh's first), read
+      in place, with the live shards sliced off on that device. Its
+      argument ``moved_bytes`` counts the bytes copied to bring the stack
+      there: 0 when the replica is read in place;
+    - ``fit.fold``: ``fold_merge`` of the live shards on that device, a
+      program of its own (the one every per-range fold runs), which is
+      what makes the result exact;
     - ``fit.place``: the folded bank replicated on the mesh.
 
     ``balls`` (a stacked bank) continues a previous fit: shards fit their
@@ -511,11 +537,14 @@ def fit_bank_sharded(
         stacked = fits(X, Y, cs)
         folded = fold_merge(jax.tree.map(lambda v: v[:n_live], stacked))
     else:
-        # Eager call: fold the per-shard banks off the mesh, on the default
-        # device, with the same eager fold per-range single-device fits are
-        # folded with; then replicate. Compiled into the mesh program, XLA
-        # may fuse the merge arithmetic differently, which on a TPU moves
-        # the last bits of the result. The host hop copies bits exactly.
+        # Eager call: fold the per-shard banks off the mesh program, on one
+        # device, with the same fold program per-range single-device fits
+        # are folded with; then replicate. Compiled into the mesh program,
+        # XLA may fuse the merge arithmetic differently, which on a TPU
+        # moves the last bits of the result. Exactness comes from that
+        # one-device fold reading the gathered bits, which the mesh program
+        # left on every mesh device: the fold reads its own device's
+        # replica in place, with no copy through the host.
         from repro.kernels.ops import bank_engine_grid  # lazy: module cycle
 
         blocks, tiles = bank_engine_grid(
@@ -527,11 +556,16 @@ def fit_bank_sharded(
             "fit.shards", gram_fills=blocks, tile_visits=blocks * tiles
         ):
             stacked = jax.block_until_ready(fits(X, Y, cs))
-        with jax.profiler.TraceAnnotation("fit.gather"):
-            host = jax.tree.map(lambda v: np.asarray(v)[:n_live], stacked)
+        dev = _fold_device(mesh)
+        moved = sum(v.nbytes for v in jax.tree.leaves(stacked)
+                    if dev not in v.devices())
+        with jax.profiler.TraceAnnotation("fit.gather", moved_bytes=moved):
+            local = jax.tree.map(lambda v: _replica_on(v, dev), stacked)
+            if n_live < n_shards:
+                local = jax.tree.map(lambda v: v[:n_live], local)
+            local = jax.block_until_ready(local)
         with jax.profiler.TraceAnnotation("fit.fold"):
-            folded = jax.block_until_ready(
-                fold_merge(jax.tree.map(jax.device_put, host)))
+            folded = jax.block_until_ready(fold_merge(local))
         with jax.profiler.TraceAnnotation("fit.place"):
             folded = jax.block_until_ready(jax.tree.map(
                 lambda v: jax.device_put(v, NamedSharding(mesh, P())), folded))

@@ -430,6 +430,7 @@ def fold_banks(banks, live=None) -> Ball:
     return fold_merge(stack_banks(banks))
 
 
+@jax.jit
 def fold_merge(balls: Ball, live: jax.Array | None = None) -> Ball:
     """Deterministic left fold of a stacked Ball pytree (leading axis).
 
@@ -443,6 +444,11 @@ def fold_merge(balls: Ball, live: jax.Array | None = None) -> Ball:
     — are excluded from the fold. The fold starts at the FIRST live entry
     (so a dead entry 0 cannot contaminate the result); at least one entry
     must be live.
+
+    Compiled once per shape: run op by op, the loop body (a fresh closure
+    each call) is traced, lowered and compiled anew on every call. Every
+    caller, the sharded fit's eager fold and the per-range folds it is
+    checked against alike, runs this one program.
     """
     n = balls.w.shape[0]
     merge = merge_balls if balls.w.ndim == 2 else merge_banks

@@ -222,6 +222,21 @@ def test_fold_merge_bank_matches_per_model_fold():
         assert int(folded.m[k]) == int(one.m)
 
 
+@pytest.mark.parametrize("live", [None, (True, False, True, True)])
+def test_fold_merge_compiles_once_per_shape(live, caplog):
+    """Folding new values of a shape it has folded before compiles nothing:
+    the sharded fit folds once a pass, and a compile there costs more than
+    the fold's work."""
+    import logging
+
+    live = None if live is None else jnp.asarray(live)
+    fold_merge(_random_balls(4, 3, 8, seed=41), live)
+    with jax.log_compiles(), caplog.at_level(logging.WARNING):
+        caplog.clear()
+        jax.block_until_ready(fold_merge(_random_balls(4, 3, 8, seed=42), live))
+    assert not [r for r in caplog.records if "Compiling" in r.getMessage()]
+
+
 def test_fold_merge_bank_axis_permutation_equivariance():
     """Model lanes never interact: permuting B commutes with the fold."""
     banks = _random_balls(4, 6, 5, seed=21)
@@ -372,6 +387,71 @@ if HAVE_HYPOTHESIS:
                 np.asarray(folded.w[k]), np.asarray(one.w), rtol=1e-6, atol=1e-7
             )
             np.testing.assert_allclose(float(folded.r[k]), float(one.r), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Four virtual devices in a child process: the eager fold's exactness
+# ---------------------------------------------------------------------------
+
+_HOST_FOLD_CHILD = r"""
+import json, sys
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import fit_bank_sharded, fold_merge
+from repro.core.distributed import _place_rows, _sharded_fits
+
+assert len(jax.devices()) == 4, jax.devices()
+mesh = jax.make_mesh((4,), ("data",))
+b, d = 12, 128
+out = {}
+for n in (256, 9):
+    rng = np.random.default_rng(n)
+    X = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    Y = jnp.asarray(np.sign(rng.normal(size=(b, n))).astype(np.float32))
+    cs = jnp.asarray(np.exp(rng.uniform(-1, 3, size=b)).astype(np.float32))
+    got = fit_bank_sharded(X, Y, cs, mesh)
+    # The route through the host: the stack copied out, cut to its live
+    # shards, copied back to the default device and folded there op by op.
+    shard_n = -(-n // 4)
+    stacked = _sharded_fits(
+        _place_rows(X, NamedSharding(mesh, P("data")), 0, shard_n * 4),
+        _place_rows(Y, NamedSharding(mesh, P(None, "data")), 1, shard_n * 4),
+        cs, mesh=mesh, axes=("data",), n_rows=n, variant="exact",
+        lookahead=None, block_n=256, b_tile=None, stream_dtype=None,
+        bank_resident="auto", interpret=None)
+    n_live = -(-n // shard_n)
+    want = fold_merge.__wrapped__(jax.tree.map(
+        lambda v: jax.device_put(np.asarray(v)[:n_live]), stacked))
+    out[n] = {"n_live": n_live, "fields": {
+        f: np.asarray(getattr(got, f)).tobytes()
+        == np.asarray(getattr(want, f)).tobytes()
+        for f in ("w", "r", "xi2", "m")}}
+print(json.dumps(out))
+"""
+
+
+def test_eager_fold_is_bit_identical_to_host_route():
+    """On four devices, the eager mesh fit folds the fold device's own
+    replica of the gathered banks with the compiled fold; the bank it
+    returns equals, bit for bit, the one folded op by op from the stack
+    copied through the host, for an even split and for a ragged stream
+    whose last shard is dead."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.abspath(src))
+    proc = subprocess.run([sys.executable, "-c", _HOST_FOLD_CHILD], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["256"]["n_live"] == 4 and out["9"]["n_live"] == 3
+    for n, case in out.items():
+        assert all(case["fields"].values()), (n, case)
 
 
 # ---------------------------------------------------------------------------

@@ -94,7 +94,9 @@ def test_block_gram_is_shared_by_the_bank_tiles(
 
 def test_mesh_fit_span_counts_gram_fills_and_tile_visits(monkeypatch):
     """The eager mesh fit's ``fit.shards`` span carries the shard's Gram
-    fills (its data blocks) and tile visits (blocks x bank tiles)."""
+    fills (its data blocks) and tile visits (blocks x bank tiles); its
+    ``fit.gather`` span reads the fold device's replica in place, moving
+    no byte."""
     spans = []
 
     class Span:
@@ -114,6 +116,7 @@ def test_mesh_fit_span_counts_gram_fills_and_tile_visits(monkeypatch):
     args = dict(spans)["fit.shards"]
     # 299 streamed rows in blocks of 64, three tiles of 8 for 21 models
     assert args == dict(gram_fills=5, tile_visits=15)
+    assert dict(spans)["fit.gather"] == dict(moved_bytes=0)
 
 
 def test_tiled_matches_bank_ref_at_8x_tile():
